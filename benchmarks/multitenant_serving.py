@@ -46,6 +46,7 @@ import numpy as np
 
 from repro import compiler
 from repro.configs.cnn import mini_mobilenet, mini_resnet18, mini_resnet50
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.cnn import cnn_input_shape, init_cnn_params
 from repro.runtime.frontend import MultiTenantFrontEnd
 
@@ -263,6 +264,7 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write the BENCH_multitenant.json artifact here")
     args = ap.parse_args()
+    enable_compile_cache()
     n_requests, n_fair = args.requests, args.fair_requests
     if args.smoke:
         n_requests = min(n_requests, 8)

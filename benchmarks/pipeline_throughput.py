@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from repro import compiler
 from repro.configs.cnn import CNN_CONFIGS, mini_resnet18
 from repro.core import fifo_sim
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.cnn import cnn_input_shape, init_cnn_params
 
 PAPER_NETS = ("resnet18", "resnet50", "vgg16")
@@ -177,6 +178,7 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write the BENCH_pipeline.json artifact here")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rows = bench(args.batch, args.repeats) + modelled_rows()
     for row in rows:

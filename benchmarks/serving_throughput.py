@@ -51,6 +51,7 @@ import numpy as np
 
 from repro import compiler
 from repro.configs.cnn import mini_resnet18
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.cnn import cnn_input_shape, init_cnn_params
 from repro.obs import Tracer
 
@@ -174,6 +175,7 @@ def main() -> None:
                     help="write a Chrome Trace Event JSON of the final "
                          "closed-loop serving repeat (Perfetto loadable)")
     args = ap.parse_args()
+    enable_compile_cache()
     n_requests, repeats = args.requests, args.repeats
     if args.smoke:
         n_requests = min(n_requests, 16)

@@ -54,6 +54,7 @@ import numpy as np                                     # noqa: E402
 from repro import compiler                             # noqa: E402
 from repro.configs.cnn import (mini_mobilenet,         # noqa: E402
                                mini_resnet18, mini_resnet50)
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import compat_make_mesh         # noqa: E402
 from repro.models.cnn import (cnn_input_shape,         # noqa: E402
                               init_cnn_params)
@@ -152,6 +153,7 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write the BENCH_sharded.json artifact here")
     args = ap.parse_args()
+    enable_compile_cache()
     n_requests = min(args.requests, 6) if args.smoke else args.requests
 
     if jax.device_count() < N_STAGES:

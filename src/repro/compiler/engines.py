@@ -72,7 +72,11 @@ import jax.numpy as jnp
 from repro.configs.cnn import (POOL_KINDS, ConvLayerSpec, ResBlockSpec,
                                StemUnitSpec)
 from repro.core.schedule import HBM, PINNED, LayerSchedule
-from repro.kernels.conv2d_int8.ops import conv2d_int8, same_padded_width
+from repro.kernels.conv2d_int8.kernel import DW_TAP_BURST, dw_tap_bursts
+from repro.kernels.conv2d_int8.ops import (conv2d_int8,
+                                          line_buffer_geometry,
+                                          same_padded_width)
+from repro.kernels.pallas_compat import LANES, round_up
 from repro.kernels.pool_int8.ops import global_avgpool_int8, maxpool_int8
 from repro.kernels.quant import requant_epilogue
 from repro.kernels.stream_matmul import ops as sm_ops
@@ -86,21 +90,27 @@ _requant = functools.partial(jax.jit, static_argnames=("act_scale", "relu"))(
 
 
 @functools.lru_cache(maxsize=None)
-def _block(n: int, cap: int) -> int:
-    """Largest divisor of n not exceeding cap (Pallas block sizing).
-    Cached: compile() probes this from every ``supports``/``vmem_bytes``
-    call, and the divisor scan is linear in n."""
-    for b in range(min(n, cap), 0, -1):
+def _block(n: int, cap: int, align: int = 128) -> int:
+    """Pallas block size for a dim of extent ``n``: the largest divisor of
+    n not exceeding ``cap`` that is a multiple of ``align`` (the TPU's
+    tiling of that dim), else the full extent ``n`` — the only two block
+    shapes Mosaic accepts.  Cached: compile() probes this from every
+    ``supports``/``vmem_bytes`` call, and the divisor scan is linear."""
+    for b in range(min(n, cap) // align * align, 0, -align):
         if n % b == 0:
             return b
-    return 1
+    return n
 
 
-def _padded_width(spec: ConvLayerSpec) -> int:
-    """SAME-padded input width (what the line buffer actually holds) —
-    from the kernel module's own padding formula, so validation and
-    allocation cannot drift apart."""
-    return same_padded_width(spec.in_w, spec.k_w, spec.stride)
+def _line_buffer_bytes(spec: ConvLayerSpec) -> int:
+    """int8 bytes of the k_h-row line buffer the conv / maxpool kernels
+    allocate — the phase-split, tiling-padded geometry from the kernel
+    module itself (``line_buffer_geometry``, built on
+    ``same_padded_width``), so validation and allocation cannot drift
+    apart."""
+    phases, w_phase, c_pad = line_buffer_geometry(
+        spec.in_w, spec.c_in, spec.k_w, spec.stride)
+    return spec.k_h * phases * w_phase * c_pad
 
 
 # ---------------------------------------------------------------------------
@@ -340,19 +350,25 @@ class Conv2DInt8Engine:
             and _fc_conv_is_valid_equivalent(spec))
 
     def vmem_bytes(self, spec: ConvLayerSpec, sched: LayerSchedule) -> int:
-        # channel factors of one weight tap: [1, C] depthwise, [C, C_out]
-        # dense.  Widths use the kernel's SAME-pad ceil, not spec's floor.
-        tap_in = 1 if self.depthwise else spec.c_in
-        c_out = spec.c_in if self.depthwise else spec.c_out
-        out_w = spec.out_w                  # SAME ceil, == kernel output
-        line_buf = spec.k_h * _padded_width(spec) * spec.c_in      # int8
-        if sched.streamed:
-            w = min(sched.n_buffers, spec.k_h * spec.k_w) \
-                * tap_in * c_out                                   # ring
+        # channel factors of one weight tap as the kernel holds it: [1, C]
+        # depthwise, [C, C_out] dense, input channels lane-padded.  Pinned
+        # weights are single-buffered (their block never changes); the
+        # output row is double-buffered by the grid pipeline.  The
+        # depthwise ring moves DW_TAP_BURST-tap bursts per slot.
+        c_in = line_buffer_geometry(spec.in_w, spec.c_in, spec.k_w,
+                                    spec.stride)[2]
+        tap_in = 1 if self.depthwise else c_in
+        c_out = c_in if self.depthwise else spec.c_out
+        taps = spec.k_h * spec.k_w
+        if sched.streamed and self.depthwise:
+            w = min(sched.n_buffers, dw_tap_bursts(spec.k_h, spec.k_w)) \
+                * DW_TAP_BURST * c_out                             # ring
+        elif sched.streamed:
+            w = min(sched.n_buffers, taps) * tap_in * c_out        # ring
         else:
-            w = spec.k_h * spec.k_w * tap_in * c_out               # pinned
-        out_row = out_w * c_out * 4                                # int32
-        return line_buf + w + 2 * out_row                          # + acc
+            w = taps * tap_in * c_out                              # pinned
+        out_row = spec.out_w * c_out * 4                           # int32
+        return _line_buffer_bytes(spec) + w + 2 * out_row
 
     def stats(self, sched: LayerSchedule, batch: int) -> LayerExecStats:
         """The shape-static stats one dispatch returns: the kernel emits
@@ -391,13 +407,19 @@ class StreamMatmulFCEngine:
     def supports(self, spec: ConvLayerSpec) -> bool:
         return _is_1x1_fc(spec)
 
+    def blocks(self, batch: int, spec: ConvLayerSpec) -> Tuple[int, int, int]:
+        """(bm, bk, bn) for a ``batch``-row call: K and N blocks are lane
+        multiples (or the full dim — 1000 classes run as one N block),
+        the M block a multiple of the int8 sublane tiling (or all rows)."""
+        return (_block(batch, self.BM, 32), _block(spec.c_in, self.BK),
+                _block(spec.c_out, self.BN))
+
     def vmem_bytes(self, spec: ConvLayerSpec, sched: LayerSchedule) -> int:
         mode = "fifo" if sched.streamed else "pinned"
-        return sm_ops.vmem_bytes(
-            mode, 1, spec.c_in, spec.c_out, 1,
-            bm=1, bk=_block(spec.c_in, self.BK),
-            bn=_block(spec.c_out, self.BN),
-            n_buffers=max(2, sched.n_buffers))
+        _, bk, bn = self.blocks(1, spec)
+        return sm_ops.vmem_bytes(mode, 1, spec.c_in, spec.c_out, 1,
+                                 bm=1, bk=bk, bn=bn,
+                                 n_buffers=max(2, sched.n_buffers))
 
     def stats(self, sched: LayerSchedule, batch: int) -> LayerExecStats:
         """One matmul dispatch == one output 'row' of weight reads."""
@@ -412,10 +434,8 @@ class StreamMatmulFCEngine:
         x2 = x.reshape(B, c_in)
         w2 = params["w"].reshape(c_in, c_out)
         mode = "fifo" if sched.streamed else "pinned"
-        y = sm_ops.stream_matmul(x2, w2, mode=mode,
-                                 bm=_block(B, self.BM),
-                                 bk=_block(c_in, self.BK),
-                                 bn=_block(c_out, self.BN),
+        bm, bk, bn = self.blocks(B, spec)
+        y = sm_ops.stream_matmul(x2, w2, mode=mode, bm=bm, bk=bk, bn=bn,
                                  n_buffers=max(2, sched.n_buffers),
                                  interpret=ctx.interpret)
         y_q, y_f = _requant(y.reshape(B, 1, 1, c_out), params["w_scale"],
@@ -442,9 +462,9 @@ class MaxPoolInt8Engine:
         return spec.kind == "maxpool"
 
     def vmem_bytes(self, spec: ConvLayerSpec, sched: LayerSchedule) -> int:
-        line_buf = spec.k_h * _padded_width(spec) * spec.c_in      # int8
-        out_row = spec.out_w * spec.c_in                           # int8
-        return line_buf + 2 * out_row
+        c_pad = round_up(spec.c_in, LANES)
+        out_row = spec.out_w * c_pad                               # int8
+        return _line_buffer_bytes(spec) + 2 * out_row
 
     def stats(self, sched: LayerSchedule, batch: int) -> LayerExecStats:
         return LayerExecStats.for_dispatch(sched, kernel=self.name,
@@ -479,7 +499,7 @@ class GlobalAvgPoolInt8Engine:
     def vmem_bytes(self, spec: ConvLayerSpec, sched: LayerSchedule) -> int:
         in_map = spec.in_h * spec.in_w * spec.c_in                 # int8
         acc = spec.c_in * 4                                        # int32
-        return in_map + acc + 2 * spec.c_in
+        return in_map + acc + 2 * acc              # + int32 sums row out
 
     def stats(self, sched: LayerSchedule, batch: int) -> LayerExecStats:
         return LayerExecStats.for_dispatch(sched, kernel=self.name,

@@ -967,10 +967,8 @@ def trace_fused(compiled: CompiledPipeline, params, images, *,
     shape-static metadata, so the trace yields both the executable and
     the exact stats template every warm run reports (executed Eq. 2
     words from the traced counters; analytic words stay on the plan).
-
-    ``images`` is donated to the executable on real backends (the
-    activation buffer is dead after dispatch); under the interpreter /
-    CPU, donation is skipped so callers can reuse input arrays.
+    ``images`` is not donated: no output has its shape, so XLA could not
+    reuse the buffer.
     """
     from repro.models.cnn import cnn_forward
 
@@ -985,9 +983,7 @@ def trace_fused(compiled: CompiledPipeline, params, images, *,
                            block_engine=block_dispatch,
                            scan_engine=scan_dispatch)
 
-    donate = () if interpret else (1,)
-    jitted = jax.jit(forward, donate_argnums=donate)
-    fn = jitted.lower(params, images).compile()     # the ONE trace
+    fn = jax.jit(forward).lower(params, images).compile()   # the ONE trace
     return FusedTrace(fn=fn, stats=tuple(stats))
 
 

@@ -30,7 +30,6 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -126,9 +125,9 @@ def pipeline_apply(layer_fn: Callable, params_staged, x_mb, *, mesh: Mesh,
         return outs
 
     p_specs = jax.tree.map(lambda _: P(axis), params_staged)
-    fn = shard_map(stage_body, mesh=mesh,
-                   in_specs=(p_specs, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_body, mesh=mesh,
+                       in_specs=(p_specs, P()), out_specs=P(),
+                       check_vma=False)
     return fn(params_staged, x_mb)
 
 
@@ -232,9 +231,9 @@ def staged_pipeline_apply(stage_fns: Sequence[Callable], params, x_mb, *,
         return jax.lax.psum(outs, axis)
 
     p_specs = jax.tree.map(lambda _: P(), params)
-    fn = shard_map(stage_body, mesh=mesh,
-                   in_specs=(p_specs, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(stage_body, mesh=mesh,
+                       in_specs=(p_specs, P()), out_specs=P(),
+                       check_vma=False)
     return fn(params, x_mb)
 
 

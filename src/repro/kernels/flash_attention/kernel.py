@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -117,9 +116,9 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                  "arbitrary")),
     )(q, k, v)
     return (o, lse) if return_lse else o
 
@@ -241,9 +240,9 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal, window, softcap,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                  "arbitrary")),
     )(q, k, v, do, lse, delta)
 
     q_spec2 = pl.BlockSpec((1, 1, bq, hd), lambda b, h, ki, qi: (b, h, qi, 0))
@@ -265,8 +264,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal, window, softcap,
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd_v), jnp.float32)],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                  "arbitrary")),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -1,62 +1,38 @@
-"""Version-portability shim for Pallas TPU.
+"""The TPU's tiling, and where Pallas kernels run.
 
-The Pallas TPU compiler-params class was renamed across jax releases
-(``pltpu.TPUCompilerParams`` -> ``pltpu.CompilerParams``), and older
-versions accept a plain dict.  Every kernel in ``repro.kernels`` builds its
-``compiler_params`` through :func:`tpu_compiler_params` so the kernels
-import and run on any jax the container ships.
+Mosaic DMAs a slice of an HBM array only in whole (SUBLANES, LANES) tiles
+of its last two dims, so the kernel wrappers pad widths and channels to
+:func:`round_up` multiples of these.
 
-The second portability axis is *where* kernels run: on a real TPU the
-Mosaic path compiles them; everywhere else (CPU CI, dev laptops) they must
-execute in interpret mode.  :func:`resolve_interpret` centralises that
-decision so callers can pass ``interpret=None`` ("do the right thing for
-this backend") while tests keep forcing ``interpret=True`` explicitly.
+On a TPU the Mosaic path compiles the kernels; on the CPU (tests, dev
+machines) they execute in interpret mode.  :func:`resolve_interpret`
+centralises that decision so callers can pass ``interpret=None`` ("do the
+right thing for this backend") while tests keep forcing ``interpret=True``
+explicitly.  Nothing here hides a missing device: a backend that fails to
+initialise raises.
 """
 from __future__ import annotations
 
-import os
-from typing import Any, Optional, Sequence
+from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
 
-# The class moved: new jax exposes ``CompilerParams``, older versions only
-# ``TPUCompilerParams``.  Oldest versions want a dict under the "mosaic" key.
-_PARAMS_CLS = getattr(pltpu, "CompilerParams",
-                      getattr(pltpu, "TPUCompilerParams", None))
+SUBLANES = 8
+LANES = 128
 
 
-def tpu_compiler_params(dimension_semantics: Optional[Sequence[str]] = None,
-                        **kwargs: Any):
-    """Build a ``compiler_params`` value accepted by this jax's pallas_call.
-
-    ``dimension_semantics`` is the per-grid-dim ("parallel" | "arbitrary")
-    tuple every repro kernel sets; extra kwargs pass through.
-    """
-    if dimension_semantics is not None:
-        kwargs["dimension_semantics"] = tuple(dimension_semantics)
-    if _PARAMS_CLS is not None:
-        return _PARAMS_CLS(**kwargs)
-    return dict(mosaic=kwargs)          # pre-dataclass jax fallback
+def round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:                    # backend probing can raise at import
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def resolve_interpret(interpret: Optional[bool] = None) -> bool:
-    """Resolve an ``interpret`` request against the running backend.
-
+    """Resolve an ``interpret`` request against the running backend:
     ``True``/``False`` are honoured verbatim; ``None`` means "interpret
-    unless a TPU is attached".  ``REPRO_INTERPRET=0/1`` overrides the
-    auto-detection (CI sets ``1`` so kernels run on CPU runners).
-    """
+    unless the default backend is a TPU"."""
     if interpret is not None:
         return bool(interpret)
-    env = os.environ.get("REPRO_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return not on_tpu()

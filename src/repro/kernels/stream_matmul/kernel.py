@@ -4,7 +4,7 @@ The paper keeps compute units fed from HBM by (a) issuing weight reads
 hundreds of cycles ahead (the address stream is deterministic), (b) deep
 burst-matching + last-stage FIFOs sized from the measured worst-case
 latency, and (c) credit-based flow control bounding the in-flight words.
-On TPU the same design maps to (DESIGN.md §2):
+On TPU the same design maps to:
 
   burst length      -> K-block depth of each HBM->VMEM DMA (``bk``)
   last-stage FIFO   -> multi-buffered VMEM scratch (``n_buffers`` slots)
@@ -40,8 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.pallas_compat import tpu_compiler_params
 
 
 # ---------------------------------------------------------------------------
@@ -96,7 +94,7 @@ def stream_matmul_kernel(x, w, *, bm: int = 128, bk: int = 512,
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), _acc_dtype(out_dtype))],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, w)
 
@@ -133,7 +131,7 @@ def _mm_manual_kernel(x_ref, w_hbm_ref, o_ref, w_buf, sems, *,
     def body(k, acc):
         slot = jax.lax.rem(k, n_buffers)
         dma(k, slot).wait()                            # freeze until landed
-        xk = jax.lax.dynamic_slice_in_dim(x_ref[...], k * bk, bk, axis=1)
+        xk = x_ref[:, pl.ds(pl.multiple_of(k * bk, bk), bk)]
         acc = acc + jnp.dot(xk, w_buf[slot],
                             preferred_element_type=acc_dtype)
         # dequeue returns the credit: reuse the slot for k + n_buffers
@@ -180,6 +178,6 @@ def stream_matmul_manual(x, w, *, bm: int = 128, bk: int = 512,
             pltpu.SemaphoreType.DMA((n_buffers,)),
         ],
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
     )(x, w)

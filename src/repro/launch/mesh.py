@@ -10,19 +10,14 @@ from __future__ import annotations
 from typing import Dict, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.models.layers import set_mesh_axis_sizes
 
 
 def compat_make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax supports them (``jax.sharding.AxisType`` only exists in newer
-    releases; older versions are Auto-only, so omitting it is equivalent)."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis explicitly ``AxisType.Auto``
+    (GSPMD-propagated sharding, what the model code's specs assume)."""
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -220,7 +220,6 @@ def _moe_ep_shardmap(params: Params, cfg, xg, top_p, top_e, cap, act):
     kernels, and only the small activation stream crosses the interconnect.
     """
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.models.layers import (_current_physical_mesh, axis_size,
                                      dp_spec)
@@ -274,12 +273,12 @@ def _moe_ep_shardmap(params: Params, cfg, xg, top_p, top_e, cap, act):
 
     g_spec = P(dp, None, None)
     meta_spec = P(dp, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         region, mesh=mesh,
         in_specs=(P("model", None, None), P("model", None, None),
                   P("model", None, None), g_spec, meta_spec, meta_spec,
                   meta_spec),
-        out_specs=g_spec, check_rep=False)
+        out_specs=g_spec, check_vma=False)
     return fn(params["w_gate"], params["w_up"], params["w_down"],
               xg, top_e, pos, gate)
 

@@ -427,7 +427,6 @@ def _flash_call(q, k, v, *, causal: bool, window: int, softcap: float):
     mesh = _current_physical_mesh()
     dp = dp_spec(q.shape[0])
     if mesh is not None and dp is not None:
-        from jax.experimental.shard_map import shard_map
         # shard heads over the model axis (TP attention; keeps the kernel
         # region free of boundary gathers for MLA's 128 heads — §Perf
         # HC2-it3).  BOTH q and kv head counts must divide the axis;
@@ -441,9 +440,9 @@ def _flash_call(q, k, v, *, causal: bool, window: int, softcap: float):
         if h_ax is not None and kv_ax is not None:
             q_spec = P(dp, None, h_ax, None)
             kv_spec = P(dp, None, kv_ax, None)
-            return shard_map(call, mesh=mesh,
-                             in_specs=(q_spec, kv_spec, kv_spec),
-                             out_specs=q_spec, check_rep=False)(q, k, v)
+            return jax.shard_map(call, mesh=mesh,
+                                 in_specs=(q_spec, kv_spec, kv_spec),
+                                 out_specs=q_spec, check_vma=False)(q, k, v)
         # Heads don't divide the model axis.  A KV-group-folded layout
         # ([B*KV, S, rep, hd] sharded over the full mesh) was tried and
         # REFUTED: the boundary reshard of q/k/v/o (replicated-over-model

@@ -12,11 +12,11 @@ a CNN under that :class:`CompiledPipeline`, through one of two backends:
     per (input shape, dtype) — a warm ``run()`` is a single XLA
     dispatch, the software analogue of the paper's point that the whole
     network IS one pipelined circuit.  Traces are cached on the
-    ``CompiledPipeline`` (shared across executors and threads); the
-    input buffer is donated on real backends.  Stats come from the
-    trace: engines return shape-static :class:`LayerExecStats` instead
-    of mutating a context, so the single trace yields the exact
-    template every warm run's :class:`ExecutionReport` replays.
+    ``CompiledPipeline`` (shared across executors and threads).  Stats
+    come from the trace: engines return shape-static
+    :class:`LayerExecStats` instead of mutating a context, so the single
+    trace yields the exact template every warm run's
+    :class:`ExecutionReport` replays.
 
 ``backend="eager"``
     The per-layer walk: each engine dispatched from Python, one jit

@@ -47,6 +47,7 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple)
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.admission import AdmissionController, AdmissionError
 from repro.core.dataflow import staged_pipeline_apply
@@ -212,7 +213,13 @@ class ShardedCnnServingEngine(ServingObsMixin):
                 boundary_shapes=bshapes, out_shape=part.out_shape(mb),
                 out_dtype=jnp.float32)
 
-        zeros = jnp.zeros(self._round_shape, jnp.int8)
+        # params and every round's packed buffer live replicated on the
+        # mesh, placed once / per round — not left on device 0 for the
+        # staged program to copy out on every dispatch
+        self._replicated = NamedSharding(self.mesh, P())
+        self.params = jax.device_put(self.params, self._replicated)
+        zeros = jax.device_put(np.zeros(self._round_shape, np.int8),
+                               self._replicated)
         self._fn = jax.jit(round_forward).lower(self.params,
                                                 zeros).compile()
 
@@ -483,11 +490,12 @@ class ShardedCnnServingEngine(ServingObsMixin):
                 if not self.admission.acquire():
                     raise AdmissionError(
                         "admission controller closed mid-serve")
+        x = jax.device_put(buf, self._replicated)
         if tracer.enabled:
             with tracer.span("dispatch", "dispatch", microbatches=k):
-                logits = self._fn(self.params, jnp.asarray(buf))
+                logits = self._fn(self.params, x)
         else:
-            logits = self._fn(self.params, jnp.asarray(buf))
+            logits = self._fn(self.params, x)
         t = self._clock()
         with self._lock:
             self._round_count += 1

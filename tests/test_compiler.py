@@ -169,11 +169,15 @@ def test_streamed_dwconv_accounts_eq2_traffic():
 
 
 # A target whose BRAM is big enough that Algorithm 1 streams nothing
-# (leaving the full chain pool free), but whose VMEM ceiling the three
-# widest conv layers (42880 B pinned, 14208 B streamed) only clear in the
-# HBM tier — the canonical stage-5 re-placement scenario.
-REPLACE_TARGET = TPU_INTERPRET.replace(bram_m20ks=10_000, vmem_bytes=25_000)
-WIDE_LAYERS = ("s1b0c1", "s1b1c0", "s1b1c1")
+# (leaving the full chain pool free), but whose VMEM ceiling the four
+# widest conv layers of stage 1 (90112 / 83968 B pinned, 32768 / 26624 B
+# streamed — the stride-2 lead conv holds two column phases in its line
+# buffer) only clear in the HBM tier, while every other layer (at most
+# 60416 B pinned) fits and no block unit does (the cheapest, the fully
+# streamed s1b1, needs 61440 B) — the canonical stage-5 re-placement
+# scenario.
+REPLACE_TARGET = TPU_INTERPRET.replace(bram_m20ks=10_000, vmem_bytes=61_000)
+WIDE_LAYERS = ("s1b0c0", "s1b0c1", "s1b1c0", "s1b1c1")
 
 
 def test_compile_replaces_overbudget_pinned_layers():
