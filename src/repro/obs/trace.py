@@ -28,7 +28,8 @@ Design constraints, in order:
 
 Tracks (Chrome ``tid`` rows, one per pipeline phase):
 ``admission`` (credit wait), ``pack`` (microbatch packing), ``dispatch``
-(XLA enqueue), ``in_flight`` (device occupancy, async), ``delivery``
+(one dispatch's host path: buffer ``fill``, ``h2d`` copy, ``launch``),
+``in_flight`` (device occupancy, async), ``delivery``
 (result unpacking), ``request`` (per-request lifetime, async), ``round``
 (sharded per-stage rounds).
 """

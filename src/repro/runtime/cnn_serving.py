@@ -60,7 +60,7 @@ from repro.obs.stall import stall_attribution
 from repro.obs.trace import NULL_TRACER, monotonic_clock
 
 __all__ = ["CnnRequest", "CnnServingEngine", "MicrobatchPacker",
-           "ServingReport", "restore_tuple_fields"]
+           "ServingReport", "restore_tuple_fields", "stamp_launch"]
 
 _STOP = object()                      # request-queue shutdown sentinel
 
@@ -84,6 +84,12 @@ class CnnRequest:
         # the submitting engine passes its injected clock's reading; the
         # bare-constructor default keeps direct (test) construction easy
         self.t_submit = time.perf_counter() if now is None else now
+        #: engine clock just after the launch of the program that carries
+        #: the request's last row, and that dispatch's ``seq``: submit ->
+        #: launch is the host's part, launch -> done the device's and the
+        #: read back's
+        self.t_launch: Optional[float] = None
+        self.launch_seq: Optional[int] = None
         self.t_done: Optional[float] = None
         self.hbm_words = 0            # useful Eq. 2 words (n * words/image)
         self._logits: Optional[np.ndarray] = None
@@ -140,9 +146,11 @@ class MicrobatchPacker:
     (one packer per shard queue there).
     """
 
-    def __init__(self, request_queue: "queue.Queue", microbatch: int):
+    def __init__(self, request_queue: "queue.Queue", microbatch: int,
+                 tracer=NULL_TRACER):
         self.queue = request_queue
         self.microbatch = microbatch
+        self.tracer = tracer
         self.cursor: Optional[List[Any]] = None      # [request, row_offset]
         self.saw_stop = False
 
@@ -150,29 +158,38 @@ class MicrobatchPacker:
         """One packed microbatch: ``(rows, filled)`` with ``rows`` a
         list of ``(request, req_offset, mb_offset, take)`` spans, or
         ``None`` when nothing is available (queue empty and
-        ``block=False``, or the stop sentinel was drained)."""
+        ``block=False``, or the stop sentinel was drained).  The
+        tracer's ``pack`` span opens once the first row is in hand, so
+        time blocked on an empty queue lies outside every span."""
+        if self.cursor is None and not self._advance(block):
+            return None
         rows: List[Tuple[CnnRequest, int, int, int]] = []
         filled = 0
-        while filled < self.microbatch:
-            if self.cursor is None:
-                if self.saw_stop:
-                    break
-                try:
-                    item = self.queue.get(block=block and filled == 0)
-                except queue.Empty:
-                    break
-                if item is _STOP:
-                    self.saw_stop = True
-                    break
-                self.cursor = [item, 0]
-            req, off = self.cursor
-            take = min(req.n - off, self.microbatch - filled)
-            rows.append((req, off, filled, take))
-            filled += take
-            self.cursor = [req, off + take] if off + take < req.n else None
-        if filled == 0:
-            return None                              # stopped and empty
+        with self.tracer.span("pack", "pack"):
+            while filled < self.microbatch and (
+                    self.cursor is not None or self._advance(False)):
+                req, off = self.cursor
+                take = min(req.n - off, self.microbatch - filled)
+                rows.append((req, off, filled, take))
+                filled += take
+                self.cursor = [req, off + take] if off + take < req.n \
+                    else None
         return rows, filled
+
+    def _advance(self, block: bool) -> bool:
+        """Put the queue's next request under the cursor; False when
+        there is none (empty and not blocking, or the stop sentinel)."""
+        if self.saw_stop:
+            return False
+        try:
+            item = self.queue.get(block=block)
+        except queue.Empty:
+            return False
+        if item is _STOP:
+            self.saw_stop = True
+            return False
+        self.cursor = [item, 0]
+        return True
 
     @property
     def depth_hint(self) -> int:
@@ -185,6 +202,16 @@ class MicrobatchPacker:
         if self.cursor is not None:
             self.cursor[0]._fail(exc)
             self.cursor = None
+
+
+def stamp_launch(rows, t: float, seq: int) -> None:
+    """Stamp ``t_launch``/``launch_seq`` on each request whose last row
+    is among ``rows`` (``(request, req_offset, mb_offset, take)``
+    spans), launched by dispatch ``seq`` at engine clock ``t``."""
+    for req, roff, _moff, take in rows:
+        if roff + take == req.n:
+            req.t_launch = t
+            req.launch_seq = seq
 
 
 def _deep_tuple(value: Any) -> Any:
@@ -489,7 +516,7 @@ class CnnServingEngine(ServingObsMixin):
         self.words_per_image = sum(
             compiled.plan.hbm_words_per_image().values())
         self._trace = None
-        self._packer = MicrobatchPacker(self._queue, microbatch)
+        self._packer = MicrobatchPacker(self._queue, microbatch, self.tracer)
         self._threads: List[threading.Thread] = []
         self._started = False
         self._stopped = False
@@ -707,7 +734,7 @@ class CnnServingEngine(ServingObsMixin):
                 # (queue empty), counted only once serving has begun —
                 # the wait for the FIRST request is not a pipeline stall
                 t_idle = self._clock()
-                pack = self._collect_pack()
+                pack = self._packer.collect()
                 if self._mb_count > 0:
                     self._gap_s += self._clock() - t_idle
                 if pack is None:
@@ -717,14 +744,6 @@ class CnnServingEngine(ServingObsMixin):
             self._fail(exc)
         finally:
             self._inflight.put(None)                 # completer sentinel
-
-    def _collect_pack(self):
-        """One packed microbatch off the host queue (the shared
-        :class:`MicrobatchPacker` greedy pad+mask policy)."""
-        if self.tracer.enabled:
-            with self.tracer.span("pack", "pack"):
-                return self._packer.collect()
-        return self._packer.collect()
 
     def _rung_for(self, filled: int) -> int:
         """Smallest ladder rung holding ``filled`` rows (the adaptive
@@ -753,6 +772,9 @@ class CnnServingEngine(ServingObsMixin):
         return got
 
     def _dispatch(self, rows, filled: int) -> None:
+        """One dispatch's host path under one ``dispatch`` span: ``fill``
+        the packed buffer, ``credit_wait``, ``h2d`` copy, ``launch``;
+        the bookkeeping after launch is the span's self time."""
         tracer = self.tracer
         # padded packed shape: the one fixed microbatch, or (adaptive)
         # the smallest warm ladder rung the collected rows fit in
@@ -760,47 +782,44 @@ class CnnServingEngine(ServingObsMixin):
             else self.microbatch
         trace = self._trace if shape_rows == self.microbatch \
             else self._trace_for(shape_rows)
-        buf = np.zeros((shape_rows,) + self._in_shape[1:], np.int8)
-        for req, roff, moff, take in rows:
-            buf[moff:moff + take] = req.images[roff:roff + take]
-        # the §V-A credit: at most ``credits`` microbatches between here
-        # and delivery — blocks the dispatcher, never the device
-        # (admission.wait_seconds_total accrues the blocked time)
-        if tracer.enabled:
+        seq = self._mb_count + 1     # only this thread advances the count
+        ids = {"rids": [req.rid for req, *_ in rows]} if tracer.enabled \
+            else {}
+        with tracer.span("dispatch", "dispatch", seq=seq, **ids):
+            with tracer.span("fill", "dispatch"):
+                buf = np.zeros((shape_rows,) + self._in_shape[1:], np.int8)
+                for req, roff, moff, take in rows:
+                    buf[moff:moff + take] = req.images[roff:roff + take]
+            # the §V-A credit: at most ``credits`` microbatches between
+            # here and delivery — blocks the dispatcher, never the device
+            # (admission.wait_seconds_total accrues the blocked time)
             with tracer.span("credit_wait", "admission"):
                 ok = self.admission.acquire()
-        else:
-            ok = self.admission.acquire()
-        if not ok:
-            raise AdmissionError("admission controller closed mid-serve")
-        if tracer.enabled:
-            with tracer.span("dispatch", "dispatch", filled=filled,
-                             shape_rows=shape_rows):
-                logits = trace.fn(self.params, jnp.asarray(buf))
-        else:
-            logits = trace.fn(self.params, jnp.asarray(buf))
-        t = self._clock()
-        with self._lock:
-            self._mb_count += 1
-            seq = self._mb_count
-            self._padded_rows += shape_rows - filled
-            self._dispatched_rows += shape_rows
-            self._shape_counts[shape_rows] = \
-                self._shape_counts.get(shape_rows, 0) + 1
-            depth = self._packer.depth_hint
-            # rebase on `is not None`: an injected clock legitimately
-            # starts at 0.0, and 0.0 is falsy — truthiness here silently
-            # broke the first engine's sample timestamps
-            self._depth_samples.append(
-                (t - self._t0 if self._t0 is not None else 0.0, depth))
-        if tracer.enabled:
-            tracer.begin("microbatch", "in_flight", seq, filled=filled)
-            tracer.counter("queue_depth", depth)
-        self.metrics.counter("serving_microbatches").inc()
-        self.metrics.counter("serving_padded_rows").inc(
-            shape_rows - filled)
-        self.metrics.gauge("serving_queue_depth").set(depth)
-        self._inflight.put((logits, rows, seq))
+            if not ok:
+                raise AdmissionError("admission controller closed mid-serve")
+            with tracer.span("h2d", "dispatch"):
+                x = jnp.asarray(buf)
+            with tracer.span("launch", "dispatch"):
+                logits = trace.fn(self.params, x)
+            t = self._clock()
+            stamp_launch(rows, t, seq)
+            with self._lock:
+                self._mb_count = seq
+                self._padded_rows += shape_rows - filled
+                self._dispatched_rows += shape_rows
+                self._shape_counts[shape_rows] = \
+                    self._shape_counts.get(shape_rows, 0) + 1
+                depth = self._packer.depth_hint
+                # rebase on `is not None`: an injected clock legitimately
+                # starts at 0.0, and 0.0 is falsy — truthiness here
+                # silently broke the first engine's sample timestamps
+                self._depth_samples.append(
+                    (t - self._t0 if self._t0 is not None else 0.0, depth))
+            if tracer.enabled:
+                tracer.begin("microbatch", "in_flight", seq, filled=filled)
+                tracer.counter("queue_depth", depth)
+            self.metrics.counter("serving_microbatches").inc()
+            self._inflight.put((logits, rows, seq))
 
     def _complete_loop(self) -> None:
         try:
@@ -815,13 +834,7 @@ class CnnServingEngine(ServingObsMixin):
                 if self.tracer.enabled:
                     self.tracer.end("microbatch", "in_flight", seq)
                 finished: List[CnnRequest] = []
-                if self.tracer.enabled:
-                    with self.tracer.span("deliver", "delivery", seq=seq):
-                        for req, roff, moff, take in rows:
-                            if req._deliver(roff, arr[moff:moff + take],
-                                            now):
-                                finished.append(req)
-                else:
+                with self.tracer.span("deliver", "delivery", seq=seq):
                     for req, roff, moff, take in rows:
                         if req._deliver(roff, arr[moff:moff + take], now):
                             finished.append(req)
@@ -846,7 +859,8 @@ class CnnServingEngine(ServingObsMixin):
                         self.metrics.counter(
                             "serving_images_done").inc(req.n)
                         if self.tracer.enabled:
-                            self.tracer.end("request", "request", req.rid)
+                            self.tracer.end("request", "request", req.rid,
+                                            launch_seq=req.launch_seq)
         except BaseException as exc:                 # pragma: no cover
             self._fail(exc)
 

@@ -58,7 +58,7 @@ from repro.obs.trace import NULL_TRACER, monotonic_clock
 from repro.runtime.cnn_serving import (_STOP, METRIC_WINDOW,
                                        REQUEST_ROW_WINDOW, CnnRequest,
                                        MicrobatchPacker, ServingObsMixin,
-                                       ServingReport)
+                                       ServingReport, stamp_launch)
 
 __all__ = ["ShardedCnnServingEngine", "ShardedServingReport"]
 
@@ -152,7 +152,7 @@ class ShardedCnnServingEngine(ServingObsMixin):
         # shard-local producers: one bounded queue + packer per stage
         self._queues = [queue.Queue(maxsize=queue_depth)
                         for _ in range(self.n_stages)]
-        self._packers = [MicrobatchPacker(q, microbatch)
+        self._packers = [MicrobatchPacker(q, microbatch, self.tracer)
                          for q in self._queues]
         self._shard_requests = [0] * self.n_stages
         self._rr_submit = 0           # round-robin producer assignment
@@ -454,13 +454,9 @@ class ShardedCnnServingEngine(ServingObsMixin):
         """Fill a round: block for the first microbatch, then greedily
         take whatever the shards have, never waiting once at least one
         microbatch is held (the packer's latency-over-occupancy policy,
-        lifted to rounds).  Short rounds are padded with empty slots."""
-        if self.tracer.enabled:
-            with self.tracer.span("pack", "pack"):
-                return self._collect_round_inner()
-        return self._collect_round_inner()
-
-    def _collect_round_inner(self):
+        lifted to rounds).  Short rounds are padded with empty slots.
+        Each microbatch's ``pack`` span is the packer's: the wait for
+        the first row lies outside every span."""
         packs: List[Tuple[list, int]] = []
         while len(packs) < self.round_microbatches:
             got = self._next_pack(block=not packs)
@@ -479,27 +475,20 @@ class ShardedCnnServingEngine(ServingObsMixin):
         # the §V-A cross-device credit: one per microbatch between
         # dispatch and delivery, across the whole mesh
         # (admission.wait_seconds_total accrues the blocked time)
-        if tracer.enabled:
-            with tracer.span("credit_wait", "admission", microbatches=k):
-                for _ in range(k):
-                    if not self.admission.acquire():
-                        raise AdmissionError(
-                            "admission controller closed mid-serve")
-        else:
+        with tracer.span("credit_wait", "admission", microbatches=k):
             for _ in range(k):
                 if not self.admission.acquire():
                     raise AdmissionError(
                         "admission controller closed mid-serve")
         x = jax.device_put(buf, self._replicated)
-        if tracer.enabled:
-            with tracer.span("dispatch", "dispatch", microbatches=k):
-                logits = self._fn(self.params, x)
-        else:
+        with tracer.span("dispatch", "dispatch", microbatches=k):
             logits = self._fn(self.params, x)
         t = self._clock()
+        seq = self._round_count + 1  # only this thread advances the count
+        for rows, _filled in packs:
+            stamp_launch(rows, t, seq)
         with self._lock:
-            self._round_count += 1
-            seq = self._round_count
+            self._round_count = seq
             self._mb_count += k
             self._padded_rows += sum(
                 self.microbatch - filled for _rows, filled in packs)
@@ -524,7 +513,6 @@ class ShardedCnnServingEngine(ServingObsMixin):
         self.metrics.counter("serving_microbatches").inc(k)
         self.metrics.counter("serving_empty_microbatches").inc(
             self.round_microbatches - k)
-        self.metrics.gauge("serving_queue_depth").set(depth)
         self._inflight.put((logits, packs, k, seq))
 
     def _complete_loop(self) -> None:
@@ -540,15 +528,7 @@ class ShardedCnnServingEngine(ServingObsMixin):
                 if self.tracer.enabled:
                     self.tracer.end("round", "in_flight", seq)
                 finished: List[CnnRequest] = []
-                if self.tracer.enabled:
-                    with self.tracer.span("deliver", "delivery", seq=seq):
-                        for m, (rows, _filled) in enumerate(packs):
-                            for req, roff, moff, take in rows:
-                                if req._deliver(
-                                        roff, arr[m, moff:moff + take],
-                                        now):
-                                    finished.append(req)
-                else:
+                with self.tracer.span("deliver", "delivery", seq=seq):
                     for m, (rows, _filled) in enumerate(packs):
                         for req, roff, moff, take in rows:
                             if req._deliver(roff, arr[m, moff:moff + take],
@@ -575,7 +555,8 @@ class ShardedCnnServingEngine(ServingObsMixin):
                         self.metrics.counter(
                             "serving_images_done").inc(req.n)
                         if self.tracer.enabled:
-                            self.tracer.end("request", "request", req.rid)
+                            self.tracer.end("request", "request", req.rid,
+                                            launch_seq=req.launch_seq)
         except BaseException as exc:                 # pragma: no cover
             self._fail(exc)
 
