@@ -73,7 +73,7 @@ from repro.configs.cnn import (POOL_KINDS, ConvLayerSpec, ResBlockSpec,
                                StemUnitSpec)
 from repro.core.schedule import HBM, PINNED, LayerSchedule
 from repro.kernels.conv2d_int8.kernel import DW_TAP_BURST, dw_tap_bursts
-from repro.kernels.conv2d_int8.ops import (conv2d_int8,
+from repro.kernels.conv2d_int8.ops import (conv2d_int8, conv_tile_for,
                                           line_buffer_geometry,
                                           same_padded_width)
 from repro.kernels.pallas_compat import LANES, round_up
@@ -140,12 +140,26 @@ class LayerExecStats:
         mode = sched.mode if mode is None else mode
         words = 0
         if mode == HBM and batch:
-            # Eq. 2 accounting: kernels re-read once per output row, per
-            # image.  (On TPU the matmul amortizes the batch dim; the
-            # paper's accelerator is batch-1, so we report paper units.)
+            # Eq. 2 accounting, in the paper's units: kernels re-read once
+            # per output row, per image (the paper's accelerator is
+            # batch-1).  The TPU kernels re-read once per tile of rows and
+            # images; the weight bytes they actually DMA per dispatch are
+            # in ``CompiledPipeline.describe()``.
             words = sched.weight_words_per_row * rows * batch
         return cls(name=sched.spec.name, mode=mode, kernel=kernel,
                    hbm_words=words)
+
+
+@dataclass(frozen=True)
+class ConvGrid:
+    """How the conv kernel sweeps one layer in one dispatch: ``bt`` images
+    x ``r`` output rows per grid step, ``steps`` grid steps, and the
+    weight bytes a streamed layer DMAs from HBM (0 when pinned)."""
+
+    bt: int
+    r: int
+    steps: int
+    weight_bytes: int
 
 
 @dataclass(frozen=True)
@@ -350,11 +364,15 @@ class Conv2DInt8Engine:
             and _fc_conv_is_valid_equivalent(spec))
 
     def vmem_bytes(self, spec: ConvLayerSpec, sched: LayerSchedule) -> int:
-        # channel factors of one weight tap as the kernel holds it: [1, C]
-        # depthwise, [C, C_out] dense, input channels lane-padded.  Pinned
-        # weights are single-buffered (their block never changes); the
-        # output row is double-buffered by the grid pipeline.  The
-        # depthwise ring moves DW_TAP_BURST-tap bursts per slot.
+        # the placement's per-engine claim, one image and one output row:
+        # the k_h-row line buffer, the weights and a double-buffered
+        # output row.  Channel factors of one weight tap as the kernel
+        # holds it: [1, C] depthwise, [C, C_out] dense, input channels
+        # lane-padded.  Pinned weights are single-buffered (their block
+        # never changes).  The depthwise ring moves DW_TAP_BURST-tap
+        # bursts per slot.  The dense kernel's tile of rows and images
+        # has a VMEM budget of its own (``conv_tile``), outside this
+        # claim, so the tile never moves a placement.
         c_in = line_buffer_geometry(spec.in_w, spec.c_in, spec.k_w,
                                     spec.stride)[2]
         tap_in = 1 if self.depthwise else c_in
@@ -369,6 +387,29 @@ class Conv2DInt8Engine:
             w = taps * tap_in * c_out                              # pinned
         out_row = spec.out_w * c_out * 4                           # int32
         return _line_buffer_bytes(spec) + w + 2 * out_row
+
+    def grid(self, spec: ConvLayerSpec, sched: LayerSchedule,
+             batch: int) -> ConvGrid:
+        """The kernel's grid for one dispatch of ``batch`` images: the
+        dense conv's tile (``conv_tile_for``, the rule the kernel itself
+        applies), the depthwise engine's one output row per step."""
+        c_pad = line_buffer_geometry(spec.in_w, spec.c_in, spec.k_w,
+                                     spec.stride)[2]
+        if self.depthwise:
+            bt, r = 1, 1
+            tap_bytes = dw_tap_bursts(spec.k_h, spec.k_w) * DW_TAP_BURST \
+                * c_pad
+        else:
+            t = conv_tile_for((batch, spec.in_h, spec.in_w, spec.c_in),
+                              (spec.k_h, spec.k_w, spec.c_in, spec.c_out),
+                              stride=spec.stride, stream=sched.streamed,
+                              n_buffers=sched.n_buffers)
+            bt, r = t.bt, t.r
+            tap_bytes = spec.k_h * spec.k_w * c_pad * spec.c_out
+        steps = (batch // bt) * (spec.out_h // r)
+        return ConvGrid(bt=bt, r=r, steps=steps,
+                        weight_bytes=steps * tap_bytes if sched.streamed
+                        else 0)
 
     def stats(self, sched: LayerSchedule, batch: int) -> LayerExecStats:
         """The shape-static stats one dispatch returns: the kernel emits

@@ -344,15 +344,26 @@ class CompiledPipeline:
         ``__dict__``, which frozen dataclasses permit)."""
         return {a.layer: a for a in self.assignments}
 
-    def describe(self) -> str:
-        """Human-readable engine table (what runs where, before it runs)."""
-        hdr = f"{'layer':12s} {'kind':7s} {'tier':7s} {'engine':14s} " \
-              f"{'vmem':>10s}  pc"
+    def describe(self, batch: int = 1) -> str:
+        """Human-readable engine table (what runs where, before it runs).
+        For each layer on a conv kernel, at a dispatch of ``batch``
+        images: its tile (images x output rows per grid step), its grid
+        steps, and the weight bytes a streamed layer DMAs from HBM."""
+        hdr = f"{'layer':12s} {'kind':7s} {'tier':7s} {'engine':22s} " \
+              f"{'vmem':>10s}  {'pc':4s} {'tile':>6s} {'steps':>6s} " \
+              f"{'w_dma':>11s}"
         rows = [hdr, "-" * len(hdr)]
         for s, a in zip(self.plan.schedules, self.assignments):
             pc = f"PC{s.pc}" if s.pc is not None else "-"
+            grid = getattr(select_engine(s.spec), "grid", None)
+            tile = steps = w_dma = "-"
+            if grid is not None:
+                g = grid(s.spec, s, batch)
+                tile, steps = f"{g.bt}x{g.r}", str(g.steps)
+                w_dma = str(g.weight_bytes) if s.streamed else "-"
             rows.append(f"{a.layer:12s} {s.spec.kind:7s} {a.mode:7s} "
-                        f"{a.engine:14s} {a.vmem_bytes:>10d}  {pc}")
+                        f"{a.engine:22s} {a.vmem_bytes:>10d}  {pc:4s} "
+                        f"{tile:>6s} {steps:>6s} {w_dma:>11s}")
         return "\n".join(rows)
 
     # -- plan conveniences --------------------------------------------------

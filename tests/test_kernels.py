@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.conv2d_int8.ops import conv2d_int8
+from repro.kernels.conv2d_int8.ops import conv2d_int8, conv_tile_for
 from repro.kernels.conv2d_int8.ref import conv2d_int8_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
@@ -83,6 +83,42 @@ def test_conv2d_int8_exact(case, rng_key):
     assert out.shape == ref.shape
     assert out.dtype == jnp.int32
     assert bool(jnp.all(out == ref)), case     # int math must be exact
+
+
+# (B, H=W, C, C_out, k, stride, streamed, n_buffers) -> the tile the rule
+# must pick for it, so each case covers the tiling it names
+TILED_CONV_CASES = {
+    "whole_map_7x7_bt2": ((2, 7, 16, 32, 3, 1, False, 2), (2, 7)),
+    "whole_map_7x7_streamed_nb3": ((4, 7, 16, 32, 3, 1, True, 3), (4, 7)),
+    "whole_map_s2_streamed": ((2, 14, 16, 32, 3, 2, True, 2), (2, 7)),
+    "rows_lt_h_out": ((1, 56, 8, 16, 3, 1, False, 2), (1, 28)),
+    "rows_lt_h_out_s2_b2": ((2, 112, 8, 16, 3, 2, False, 2), (1, 28)),
+    "rows_lt_h_out_streamed": ((2, 96, 8, 16, 3, 2, True, 2), (1, 24)),
+    "bt2_of_4": ((4, 24, 8, 16, 3, 1, False, 2), (2, 24)),
+    "bt2_of_4_1x1s2_streamed_nb3": ((4, 48, 8, 16, 1, 2, True, 3), (2, 24)),
+    "batch1_5x5s2": ((1, 12, 4, 8, 5, 2, False, 2), (1, 6)),
+    "fc_as_conv_7x7s7_streamed": ((4, 7, 32, 64, 7, 7, True, 2), (4, 1)),
+    "fc_as_conv_7x7s7_pinned": ((2, 7, 32, 64, 7, 7, False, 2), (2, 1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILED_CONV_CASES))
+def test_conv2d_int8_tiled_exact(case, rng_key):
+    """The tiled kernel (tiles of images x output rows, line buffer
+    prefetched across grid steps, one dot per tap per tile) is
+    bit-identical to the reference in both weight tiers."""
+    (B, hw, C, Co, k, s, stream, nb), want = TILED_CONV_CASES[case]
+    tile = conv_tile_for((B, hw, hw, C), (k, k, C, Co), stride=s,
+                         stream=stream, n_buffers=nb)
+    assert (tile.bt, tile.r) == want, tile
+    kx, kw = jax.random.split(rng_key)
+    x = jax.random.randint(kx, (B, hw, hw, C), -127, 128, jnp.int8)
+    w = jax.random.randint(kw, (k, k, C, Co), -127, 128, jnp.int8)
+    out = conv2d_int8(x, w, stride=s, stream=stream, n_buffers=nb,
+                      interpret=True)
+    ref = conv2d_int8_ref(x, w, stride=s)
+    assert out.shape == ref.shape and out.dtype == jnp.int32
+    assert bool(jnp.all(out == ref)), case
 
 
 # ---------------------------------------------------------------------------

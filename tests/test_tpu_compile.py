@@ -2,8 +2,9 @@
 
 Interpret mode cannot see what Mosaic refuses: unaligned DMA slices,
 strided int8 slices, int8 VPU arithmetic, block shapes off the (8, 128)
-tiling.  These tests lower each engine's kernel at the widths the paper's
-networks use (ResNet-50 / VGG-16 / MobileNetV2 at 224x224, batch 8) and
+tiling, more VMEM than Mosaic's scoped limit.  These tests lower each
+engine's kernel at the widths the paper's networks use (ResNet-50 /
+VGG-16 / MobileNetV2 at 224x224, batch 8; VGG-16's convs at 1 and 16) and
 compile it with the TPU compiler for a chip that is described, not
 attached.  Nothing runs; a pass means the chip's compiler accepts the
 kernel and emits a Mosaic custom call for it.
@@ -63,16 +64,33 @@ CONV_CASES = {
     "conv3x3s1_7x7_c512": (7, 512, 512, 3, 1, True),   # ResNet-50 s3 c1
     "pw1x1_c64": (56, 64, 256, 1, 1, False),           # ResNet-50 s0 c2
     "pw1x1s2_ds": (56, 256, 512, 1, 2, False),         # ResNet-50 s1b0 ds
+    "vgg_conv1_224_c64": (224, 64, 64, 3, 1, False),   # VGG-16 conv1
+    "vgg_conv8_streamed": (28, 512, 512, 3, 1, True),  # VGG-16 conv8
+    "vgg_fc0_7x7s7_streamed": (7, 512, 4096, 7, 7, True),  # VGG-16 fc0
 }
 
+#: VGG-16's shapes also at the saturation cells' microbatch and at one
+#: image, the largest and the smallest tiles the rule gives them
+VGG_CASES = sorted(c for c in CONV_CASES if c.startswith("vgg_"))
 
-@pytest.mark.parametrize("case", sorted(CONV_CASES))
-def test_conv2d_int8_compiles(one_chip, case):
+
+def _compile_conv(one_chip, case, batch):
     hw, c_in, c_out, k, stride, stream = CONV_CASES[case]
     _compile_for_chip(
         lambda x, w: conv2d_int8(x, w, stride=stride, stream=stream,
                                  interpret=False),
-        one_chip, (B, hw, hw, c_in), (k, k, c_in, c_out))
+        one_chip, (batch, hw, hw, c_in), (k, k, c_in, c_out))
+
+
+@pytest.mark.parametrize("case", sorted(CONV_CASES))
+def test_conv2d_int8_compiles(one_chip, case):
+    _compile_conv(one_chip, case, B)
+
+
+@pytest.mark.parametrize("batch", [1, 16])
+@pytest.mark.parametrize("case", VGG_CASES)
+def test_conv2d_int8_compiles_at_batch(one_chip, case, batch):
+    _compile_conv(one_chip, case, batch)
 
 
 @pytest.mark.parametrize("stride,hw,c", [(1, 112, 32), (2, 112, 96)])
