@@ -6,9 +6,11 @@ output each moved once.  Padding rows, int32 intermediates and weights
 re-read per output row are the implementation's, and are not counted, so
 a share of a roofline computed from these numbers cannot pass 100%.
 
-A row of the table is ``[name, kind, k_h, k_w, c_in, c_out, stride,
+A row of the table begins ``[name, kind, k_h, k_w, c_in, c_out, stride,
 in_h, in_w]``; outputs are SAME-sized (``ceil(in / stride)``), an fc
-layer's is 1x1.
+layer's is 1x1.  Columns after the ninth (an activation, a block, a join)
+are for the program and the configuration's own reference, and are not
+read here.
 """
 from __future__ import annotations
 
@@ -64,7 +66,7 @@ class Layer:
 
 
 def layers_of(table: Iterable[Sequence]) -> tuple:
-    return tuple(Layer(*row) for row in table)
+    return tuple(Layer(*row[:9]) for row in table)
 
 
 def macs_per_image(layers: Sequence[Layer]) -> int:
@@ -73,9 +75,14 @@ def macs_per_image(layers: Sequence[Layer]) -> int:
 
 def kernel_family(layer: Layer, engine: str, fc_engines) -> str:
     """The kernel family that computes ``layer`` when the program binds
-    it to ``engine``: ``fc`` for the streamed-matmul engines, ``pool`` for
-    pooling nodes, ``conv`` for every other weighted layer (conv engines,
-    residual-block and stem engines, and an fc layer run as a conv)."""
+    it to ``engine``: the layer's own ``family`` where it has one (a
+    configuration's ``layers_of`` may give it), else ``fc`` for the
+    streamed-matmul engines, ``pool`` for pooling nodes, ``conv`` for every
+    other weighted layer (conv engines, residual-block and stem engines,
+    and an fc layer run as a conv)."""
+    family = getattr(layer, "family", None)
+    if family:
+        return family
     if layer.kind in POOL_KINDS:
         return "pool"
     return "fc" if engine in fc_engines else "conv"

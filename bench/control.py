@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
 """The control of a cell's comparison: the reference in the program's place,
 in the nearest lower precision (int4 weights for the int8 configurations).
+The reference, its weights and its layers are the configuration's own
+(``spec.reference_of``).
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3
 
@@ -22,7 +24,7 @@ from pathlib import Path
 BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
 
-from benchlib import check, model, reference, spec, traffic  # noqa: E402
+from benchlib import check, model, spec, traffic  # noqa: E402
 
 
 def control(cell, seed: int, weight_bits: int = 4) -> dict:
@@ -34,12 +36,13 @@ def control(cell, seed: int, weight_bits: int = 4) -> dict:
     offs = rng.integers(0, wl["pool_images"] - sizes + 1)
     pool = model.image_pool(seed, wl["pool_images"], conf["image"])
     images = np.concatenate([pool[o:o + s] for o, s in zip(offs, sizes)])
-    params = model.make_params(model.seed_key(seed), conf["layers"],
-                               conf["act_scale"])
+    ref = spec.reference_of(conf)
+    params = ref.make_params(model.seed_key(seed), conf["layers"],
+                             conf["act_scale"])
     kw = dict(act_scale=conf["act_scale"], block=wl["check"]["block"])
-    want = reference.logits_in_blocks(params, conf["layers"], images, **kw)
-    got = reference.logits_in_blocks(params, conf["layers"], images,
-                                     weight_bits=weight_bits, **kw)
+    want = ref.logits_in_blocks(params, conf["layers"], images, **kw)
+    got = ref.logits_in_blocks(params, conf["layers"], images,
+                               weight_bits=weight_bits, **kw)
     ok, checks = check.compare(got, want, 0, wl["check"]["limits"])
     return {"seed": seed, "images": int(len(images)),
             "weight_bits": weight_bits, "correct": ok,

@@ -7,16 +7,18 @@ Run from the repository root on a machine that holds the chips the cell
 asks for.  One process, no children.  In order:
 
 1. load the cell's files by name (``benchlib/spec.py``);
-2. make the weights on the device and the image pool on the host, both
-   from ``--seed`` (``benchlib/model.py``);
+2. make the weights on the device (the configuration's ``make_params``,
+   by default ``benchlib/model.py``'s) and the image pool on the host,
+   both from ``--seed``;
 3. ``compiler.compile()`` the configuration and start the serving engine
    the workload names (``serve`` or ``serve_sharded``);
 4. warm every shape the window will dispatch, and only those;
 5. drive the engine with the cell's traffic mix for ``--seconds``
    (``benchlib/traffic.py``); under ``--trace 1`` the profiler records the
    window's first ``TRACE_SECONDS`` and the per-layer metrics read them;
-6. compare a sample of the answers with the plain reference
-   (``benchlib/reference.py``, ``benchlib/check.py``);
+6. compare a sample of the answers with the configuration's plain
+   reference (``spec.reference_of``: ``benchlib/reference.py`` unless the
+   configuration has its own) by ``benchlib/check.py``;
 7. print the metrics the cell reports, each read by its own
    ``bench/metrics/<name>.py``, as the last line of standard output.
 
@@ -117,10 +119,11 @@ def log(msg: str) -> None:
 
 
 def program_config(conf: dict):
-    """The configuration file's layer table as the program's CNNConfig."""
+    """The configuration file's layer table as the program's CNNConfig,
+    from the first nine columns of each row (``benchlib/spec.py``)."""
     from repro.configs.cnn import CNNConfig, ConvLayerSpec
     return CNNConfig(conf["network"],
-                     tuple(ConvLayerSpec(*row) for row in conf["layers"]),
+                     tuple(ConvLayerSpec(*row[:9]) for row in conf["layers"]),
                      num_classes=conf["num_classes"])
 
 
@@ -174,7 +177,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     import jax
     import numpy as np
 
-    from benchlib import check, model, reference, traffic, work
+    from benchlib import check, model, traffic
     from benchlib.record import Run
     from benchlib.spans import HostSpans
     from repro import compiler
@@ -184,8 +187,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     act_scale = conf["act_scale"]
     dev = devices[0]
     peaks = spec.peaks(dev.device_kind) if dev.platform == "tpu" else {}
+    ref = spec.reference_of(conf)
+    fams = spec.families(conf)
 
-    params = model.make_params(model.seed_key(seed), layers, act_scale)
+    params = ref.make_params(model.seed_key(seed), layers, act_scale)
     pool = model.image_pool(seed, wl["pool_images"], conf["image"])
     jax.block_until_ready(params)
 
@@ -195,7 +200,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
     if "jnp_ref" in engines:
         raise RuntimeError("a layer is bound to the jnp reference engine")
     log(f"{cell.name}: {len(cp.assignments)} nodes on {engines}, "
-        f"streamed {list(cp.streamed_names)}")
+        f"streamed {list(cp.streamed_names)}; reference {ref.path.name}")
 
     spans = HostSpans() if trace else None
     t_warm = time.perf_counter()
@@ -254,10 +259,9 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
         raise RuntimeError(f"{in_window} compilation(s) inside the window")
 
     log(f"collector pauses in the window: {pauses.summary()}")
-    fams = spec.families()
     rec = Run(seconds=t_rec - t0, t0=t0, t_end=t_rec, chips=len(devices),
               setup_s=setup_s, warmup_s=warmup_s, sent=sent, before=before,
-              after=after, peaks=peaks, layers=work.layers_of(layers),
+              after=after, peaks=peaks, layers=ref.layers_of(layers),
               engines=engine_of, fc_engines=fams["fc_engines"])
     if trace:
         from benchlib import trace as tr
@@ -278,7 +282,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices,
         served = np.concatenate([s.request.result() for s in picked])
         images = np.concatenate([pool[s.offset:s.offset + s.n]
                                  for s in picked])
-        want = reference.logits_in_blocks(
+        want = ref.logits_in_blocks(
             params, layers, images, act_scale=act_scale,
             block=wl["check"]["block"])
     else:

@@ -44,7 +44,30 @@ def test_config_file_matches_its_entry(conf):
     assert data["source"] == conf["source"]
     assert data["reduced"] == conf["reduced"]
     assert any(w["config"] == conf["name"] for w in B["workloads"])
-    assert all(len(row) == 9 for row in data["layers"])
+    assert all(len(row) >= 9 for row in data["layers"])
+
+
+@pytest.mark.parametrize("conf", B["configs"], ids=lambda c: c["name"])
+def test_config_resolves_its_reference_and_families(conf):
+    data = json.loads((ROOT / conf["file"]).read_text())
+    ref = spec.reference_of(data)
+    assert callable(ref.logits_in_blocks) and ref.path.is_file()
+    layers = ref.layers_of(data["layers"])
+    assert [l.name for l in layers] == [row[0] for row in data["layers"]]
+    prefixes = [p for ps in spec.families(data)["kernels"].values()
+                for p in ps]
+    assert len(prefixes) == len(set(prefixes))
+
+
+@pytest.mark.parametrize("cell,metrics", [
+    ("resnet50-x4-staged", {"images_per_s", "setup_s", "mfu",
+                            "idle_share.sat", "collective_share",
+                            "step_ms.sat", "conv_roofline", "warmup_s"}),
+])
+def test_cell_reports_exactly(cell, metrics):
+    c = spec.load_cell(cell)
+    assert c.chips == 4
+    assert {m["name"] for m in c.end_to_end + c.per_layer} == metrics
 
 
 @pytest.mark.parametrize("cell", CELLS)

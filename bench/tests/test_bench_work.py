@@ -78,7 +78,8 @@ def test_reference_equals_the_program_reference_bit_for_bit(builder):
     assert np.std(got) > 0.5                 # not saturated, not vanished
 
 
-@pytest.mark.parametrize("cell", ["resnet50-sat", "resnet50-online"])
+@pytest.mark.parametrize("cell", ["resnet50-sat", "resnet50-online",
+                                  "resnet50-x4-staged"])
 def test_the_int4_control_fails_the_cells_comparison(cell):
     """``bench/control.py`` at mini size: the reference with int4 weights
     in the program's place is not correct under the cell's own limits."""
