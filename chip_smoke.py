@@ -107,13 +107,13 @@ def first_differing_layer(cp, params, x, *, interpret: bool):
     ctx = EngineContext(interpret=interpret, act_scale=0.05)
     found = []
 
-    def both(spec, p, xin, relu):
+    def both(spec, p, xin, act):
         y_q, y_f, _ = select_engine(spec).run(
-            ctx, cp.plan.schedule_for(spec.name), p, xin, relu)
+            ctx, cp.plan.schedule_for(spec.name), p, xin, act)
         if spec.is_pool:
             r_q, r_f = pool_forward(spec, xin), None
         else:
-            r_q, r_f = conv_layer_forward(p, spec, xin, relu=relu)
+            r_q, r_f = conv_layer_forward(p, spec, xin, act=act)
         dq = int(jnp.max(jnp.abs(y_q.astype(jnp.int32)
                                  - r_q.astype(jnp.int32))))
         df = 0.0 if r_f is None else float(jnp.max(jnp.abs(y_f - r_f)))

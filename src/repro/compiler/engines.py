@@ -14,8 +14,10 @@ and declares
                                   against the Target's VMEM budget and
                                   re-place (pin -> stream) the ones that
                                   do not fit;
-  * ``run(ctx, sched, params, x, relu)``
-                                  the actual dispatch.  ``ctx`` is a
+  * ``run(ctx, sched, params, x, act)``
+                                  the actual dispatch (``act``: the
+                                  layer's activation, ``"relu"``,
+                                  ``"relu6"`` or ``"none"``).  ``ctx`` is a
                                   per-execution :class:`EngineContext`
                                   (interpret flag, activation scale) —
                                   engines hold NO mutable state and
@@ -36,20 +38,22 @@ conv, a Winograd path, an FPGA RTL emitter...) requires no edits here:
     class MyConvEngine:
         def supports(self, spec): ...
         def vmem_bytes(self, spec, sched): ...
-        def run(self, ctx, sched, params, x, relu): ...
+        def run(self, ctx, sched, params, x, act): ...
 
 Block engines (``is_block = True``) bind a whole :class:`ResBlockSpec`
 instead of one layer: ``supports``/``vmem_bytes``/``run`` take the block
 (and the member schedules), and the compiler emits one schedulable unit
 for the group — ``res_block_int8`` fuses a residual block's conv chain,
-downsample, add and relu the way the paper places whole engines.
+downsample, add and join activation the way the paper places whole
+engines.
 
 Built-in engines: ``conv2d_int8`` (dense/pointwise conv + big fc-as-conv
 heads), ``dwconv_int8`` (grouped depthwise — the MobileNet path),
 ``stream_matmul`` (1x1 fc heads), ``maxpool_int8`` / ``global_avgpool_int8``
 (the weightless pooling topology nodes — line-buffer comparators and
 channel accumulators, never streamed, zero Eq. 2 words), ``res_block_int8``
-(fused residual blocks — basic AND bottleneck), ``jnp_ref`` (XLA
+(fused residual blocks — basic and bottleneck, and MobileNetV2's inverted
+residuals), ``jnp_ref`` (XLA
 reference, priority 0 safety net).
 
 Every engine also exposes ``stats(sched, batch)`` — the shape-static
@@ -69,23 +73,23 @@ from typing import (Any, Dict, List, Optional, Protocol, Sequence, Tuple,
 import jax
 import jax.numpy as jnp
 
-from repro.configs.cnn import (POOL_KINDS, ConvLayerSpec, ResBlockSpec,
-                               StemUnitSpec)
+from repro.configs.cnn import (LINEAR, POOL_KINDS, ConvLayerSpec,
+                               ResBlockSpec, StemUnitSpec)
 from repro.core.schedule import HBM, PINNED, LayerSchedule
 from repro.kernels.conv2d_int8.kernel import DW_TAP_BURST, dw_tap_bursts
 from repro.kernels.conv2d_int8.ops import (conv2d_int8, conv_tile_for,
-                                          line_buffer_geometry,
+                                          dwconv_int8, line_buffer_geometry,
                                           same_padded_width)
 from repro.kernels.pallas_compat import LANES, round_up
 from repro.kernels.pool_int8.ops import global_avgpool_int8, maxpool_int8
-from repro.kernels.quant import requant_epilogue
+from repro.kernels.quant import requant_epilogue, residual_join
 from repro.kernels.stream_matmul import ops as sm_ops
 
 Params = Dict[str, Any]
 
-# the ONE dequant+bias+relu+requant epilogue (kernels/quant.py), jitted so
-# its float ops compile exactly like the reference path's
-_requant = functools.partial(jax.jit, static_argnames=("act_scale", "relu"))(
+# the ONE dequant+bias+activation+requant epilogue (kernels/quant.py),
+# jitted so its float ops compile exactly like the reference path's
+_requant = functools.partial(jax.jit, static_argnames=("act_scale", "act"))(
     requant_epilogue)
 
 
@@ -215,7 +219,7 @@ class LayerEngine(Protocol):
         ...
 
     def run(self, ctx: EngineContext, sched: LayerSchedule, params: Params,
-            x: jnp.ndarray, relu: bool
+            x: jnp.ndarray, act: str
             ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray], LayerExecStats]:
         """Execute the layer; returns (int8 activations, float pre-quant,
         dispatch stats).  Stats are shape-static — safe under a trace."""
@@ -420,13 +424,13 @@ class Conv2DInt8Engine:
                                            rows=sched.spec.out_h)
 
     def run(self, ctx: EngineContext, sched: LayerSchedule, params: Params,
-            x, relu: bool):
+            x, act: str):
         spec = sched.spec
-        y = conv2d_int8(x, params["w"], stride=spec.stride,
-                        stream=sched.streamed, n_buffers=sched.n_buffers,
-                        depthwise=self.depthwise, interpret=ctx.interpret)
+        conv = dwconv_int8 if self.depthwise else conv2d_int8
+        y = conv(x, params["w"], stride=spec.stride, stream=sched.streamed,
+                 n_buffers=sched.n_buffers, interpret=ctx.interpret)
         y_q, y_f = _requant(y, params["w_scale"], params["bias"],
-                            act_scale=ctx.act_scale, relu=relu)
+                            act_scale=ctx.act_scale, act=act)
         stats = LayerExecStats.for_dispatch(
             sched, kernel=self.name, batch=int(x.shape[0]),
             rows=int(y.shape[1]))
@@ -468,7 +472,7 @@ class StreamMatmulFCEngine:
                                            batch=batch, rows=1)
 
     def run(self, ctx: EngineContext, sched: LayerSchedule, params: Params,
-            x, relu: bool):
+            x, act: str):
         spec = sched.spec
         B = int(x.shape[0])
         c_in, c_out = spec.c_in, spec.c_out
@@ -480,8 +484,7 @@ class StreamMatmulFCEngine:
                                  n_buffers=max(2, sched.n_buffers),
                                  interpret=ctx.interpret)
         y_q, y_f = _requant(y.reshape(B, 1, 1, c_out), params["w_scale"],
-                            params["bias"], act_scale=ctx.act_scale,
-                            relu=relu)
+                            params["bias"], act_scale=ctx.act_scale, act=act)
         stats = LayerExecStats.for_dispatch(sched, kernel=self.name,
                                             batch=B, rows=1)
         return y_q, y_f, stats
@@ -514,7 +517,7 @@ class MaxPoolInt8Engine:
                                            mode=PINNED)
 
     def run(self, ctx: EngineContext, sched: LayerSchedule, params: Params,
-            x, relu: bool):
+            x, act: str):
         spec = sched.spec
         y = maxpool_int8(x, k=spec.k_h, stride=spec.stride,
                          interpret=ctx.interpret)
@@ -547,7 +550,7 @@ class GlobalAvgPoolInt8Engine:
                                            batch=batch, rows=1, mode=PINNED)
 
     def run(self, ctx: EngineContext, sched: LayerSchedule, params: Params,
-            x, relu: bool):
+            x, act: str):
         y = global_avgpool_int8(x, act_scale=ctx.act_scale,
                                 interpret=ctx.interpret)
         stats = LayerExecStats.for_dispatch(
@@ -581,7 +584,7 @@ class JnpReferenceEngine:
                                            batch=0, mode=PINNED)
 
     def run(self, ctx: EngineContext, sched: LayerSchedule, params: Params,
-            x, relu: bool):
+            x, act: str):
         from repro.models.cnn import conv_layer_forward, pool_forward
         spec = sched.spec
         stats = LayerExecStats.for_dispatch(sched, kernel=self.name,
@@ -589,16 +592,17 @@ class JnpReferenceEngine:
         if spec.kind in POOL_KINDS:
             return pool_forward(spec, x, act_scale=ctx.act_scale), None, stats
         y_q, y_f = conv_layer_forward(params, spec, x,
-                                      act_scale=ctx.act_scale, relu=relu)
+                                      act_scale=ctx.act_scale, act=act)
         return y_q, y_f, stats
 
 
 @register_engine("res_block_int8", priority=10)
 class ResBlockInt8Engine:
     """A whole residual block — conv chain, identity downsample, int32
-    add, clip and relu — as ONE schedulable unit, the granularity the
-    paper actually places: an engine is a block of fabric, not a Python
-    loop iteration.  Member convs execute on their per-layer engines
+    add, clip and the join's activation (ReLU for ResNet, none for
+    MobileNetV2's inverted residuals) — as ONE schedulable unit, the
+    granularity the paper actually places: an engine is a block of
+    fabric, not a Python loop iteration.  Member convs execute on their per-layer engines
     (pinned or HBM-streamed per the member schedules), the join runs
     in-engine, and the unit reports per-member Eq. 2 stats under this
     engine's name — the compile-time binding is exactly what runs.
@@ -660,24 +664,21 @@ class ResBlockInt8Engine:
         by_name = {s.spec.name: s for s in scheds}
         stats: List[LayerExecStats] = []
 
-        def member(spec: ConvLayerSpec, xin, relu: bool):
+        def member(spec: ConvLayerSpec, xin):
             y_q, _, st = select_engine(spec).run(
-                ctx, by_name[spec.name], params[spec.name], xin, relu)
+                ctx, by_name[spec.name], params[spec.name], xin,
+                block.act_of(spec))
             # the block IS the binding: members report under its name
             stats.append(dataclasses.replace(st, kernel=self.name))
             return y_q
 
         h = x
-        last = len(block.convs) - 1
-        for ci, cspec in enumerate(block.convs):
-            h = member(cspec, h, relu=ci != last)
+        for cspec in block.convs:
+            h = member(cspec, h)
         identity = x
         if block.ds is not None:
-            identity = member(block.ds, identity, relu=False)
-        y = h.astype(jnp.int32) + identity.astype(jnp.int32)
-        y = jnp.clip(y, -127, 127).astype(jnp.int8)
-        y = jnp.where(y > 0, y, 0)                    # relu on int8
-        return y, tuple(stats)
+            identity = member(block.ds, identity)
+        return residual_join(h, identity, block.join), tuple(stats)
 
 
 @register_engine("scanned_res_block_int8", priority=10)
@@ -807,8 +808,8 @@ class StemPoolInt8Engine:
         cs, ps = scheds
         stats: List[LayerExecStats] = []
         y, _, st = select_engine(unit.conv).run(
-            ctx, cs, params[unit.conv.name], x, True)
+            ctx, cs, params[unit.conv.name], x, unit.act)
         stats.append(dataclasses.replace(st, kernel=self.name))
-        y, _, st = select_engine(unit.pool).run(ctx, ps, {}, y, False)
+        y, _, st = select_engine(unit.pool).run(ctx, ps, {}, y, LINEAR)
         stats.append(dataclasses.replace(st, kernel=self.name))
         return y, tuple(stats)

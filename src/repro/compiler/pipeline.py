@@ -595,6 +595,16 @@ class ExecutionReport:
         engine_table for layers the pipeline dispatched)."""
         return {st.name: st.kernel for st in self.layers}
 
+    def dispatch_counts(self) -> Dict[str, int]:
+        """engine -> the layer dispatches it made: members of a fused
+        unit count under the unit's engine (``res_block_int8``,
+        ``scanned_res_block_int8``, ``stem_pool_int8``), every other
+        layer under its own (``conv2d_int8``, ``dwconv_int8``, ...)."""
+        out: Dict[str, int] = {}
+        for st in self.layers:
+            out[st.kernel] = out.get(st.kernel, 0) + 1
+        return out
+
     def block_rows(self) -> List[Dict[str, Any]]:
         """Block-granular Eq. 2 rows: one per fused block unit, with the
         EXECUTED streamed words of its members (from the dispatch
@@ -924,14 +934,14 @@ def make_dispatchers(compiled: CompiledPipeline, ctx: EngineContext,
     call) and the stage-6 trace (collecting once, at trace time)."""
     plan = compiled.plan
 
-    def dispatch(spec, p, x, relu: bool):
+    def dispatch(spec, p, x, act: str):
         asn = compiled.assignment_for(spec.name)
         if asn is None or asn.block is not None:
             # unknown to the plan, or owned by a fused block unit (the
             # block hook handles it) -> decline, jnp reference runs it
             return None
         y_q, y_f, st = get_engine(asn.engine).run(
-            ctx, plan.schedule_for(spec.name), p, x, relu)
+            ctx, plan.schedule_for(spec.name), p, x, act)
         if collect is not None:
             collect.append(st)
         return y_q, y_f

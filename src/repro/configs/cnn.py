@@ -23,8 +23,9 @@ buffers.
 from __future__ import annotations
 
 import dataclasses
+import re
 from dataclasses import dataclass
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 #: Weightless topology kinds: placed and costed like any engine, but with
 #: no weight memory, no Eq. 2 traffic, and no AI-TB parallelism to balance.
@@ -153,17 +154,38 @@ class CNNConfig:
         return CNNConfig(self.name + "-reduced", tuple(small), num_classes=10)
 
 
+#: the activations a layer or a residual join applies after it
+#: requantizes: ReLU, ReLU6 (clipped to [0, 6.0] in float), or none (a
+#: linear projection, the logits)
+RELU, RELU6, LINEAR = "relu", "relu6", "none"
+
+#: the rows of one MobileNetV2 inverted-residual block: ``ir{i}ex`` (the
+#: 1x1 expansion, absent when t = 1), ``ir{i}dw`` and ``ir{i}pj``
+_IR = re.compile(r"^(ir\d+)(ex|dw|pj)$")
+
+
+def _is_mobilenetv2(cfg: "CNNConfig") -> bool:
+    return cfg.name.startswith("mobilenetv2")
+
+
 @dataclass(frozen=True)
 class ResBlockSpec:
     """One residual block as a schedulable unit: the conv chain, the
-    optional pointwise downsample on the identity path, and the add+relu
-    join.  H2PIPE places whole engines, not abstract layers — grouping
-    the block lets the compiler bind it to a single fused engine
-    (``res_block_int8``) with its own VMEM cost and Eq. 2 accounting."""
+    optional pointwise downsample on the identity path, and the join.
+    H2PIPE places whole engines, not abstract layers — grouping the
+    block lets the compiler bind it to a single fused engine
+    (``res_block_int8``) with its own VMEM cost and Eq. 2 accounting.
 
-    name: str                           # "s{i}b{j}" block prefix
+    ``acts`` is each member's activation, in ``members`` order; ``join``
+    the activation after the int32 add and its clip to int8: ``RELU``
+    for ResNet's blocks, ``LINEAR`` for MobileNetV2's inverted
+    residuals."""
+
+    name: str                           # "s{i}b{j}" / "ir{i}" prefix
     convs: Tuple[ConvLayerSpec, ...]    # main-path convs, pipeline order
     ds: Optional[ConvLayerSpec]         # identity-path downsample (or None)
+    acts: Tuple[str, ...]               # per member, ``members`` order
+    join: str                           # RELU | LINEAR
 
     @property
     def members(self) -> Tuple[ConvLayerSpec, ...]:
@@ -171,13 +193,70 @@ class ResBlockSpec:
         the order the config builders emit them)."""
         return self.convs + ((self.ds,) if self.ds is not None else ())
 
+    def act_of(self, spec: ConvLayerSpec) -> str:
+        """The activation of member ``spec``."""
+        return self.acts[self.members.index(spec)]
+
+
+def _activation(cfg: "CNNConfig", spec: ConvLayerSpec) -> str:
+    """A weighted layer's activation outside a ResNet block: ReLU6 in
+    MobileNetV2 but for its linear projections, ReLU elsewhere; the last
+    layer (the logits) is linear."""
+    if spec.name == cfg.layers[-1].name:
+        return LINEAR
+    if _is_mobilenetv2(cfg):
+        m = _IR.match(spec.name)
+        return LINEAR if m is not None and m.group(2) == "pj" else RELU6
+    return RELU
+
+
+def layer_activations(cfg: "CNNConfig") -> Dict[str, str]:
+    """layer name -> the activation it applies after requantizing: the
+    one table ``cnn_forward`` reads, built from the same rules as the
+    blocks' ``acts``.  Pool nodes apply none."""
+    acts = {l.name: LINEAR if l.is_pool else _activation(cfg, l)
+            for l in cfg.layers}
+    for b in residual_blocks(cfg):
+        acts.update(zip((m.name for m in b.members), b.acts))
+    return acts
+
+
+def _inverted_residuals(cfg: "CNNConfig") -> Tuple[ResBlockSpec, ...]:
+    """MobileNetV2's inverted residuals (arXiv:1801.04381, Fig. 4(d)):
+    the ``ir{i}ex`` / ``ir{i}dw`` / ``ir{i}pj`` rows of block ``i`` with
+    an identity skip, which a block has iff its depthwise stride is 1
+    and its input and output widths agree.  Blocks without the skip are
+    plain layers."""
+    groups: Dict[str, List[ConvLayerSpec]] = {}
+    for l in cfg.layers:
+        m = _IR.match(l.name)
+        if m is not None:
+            groups.setdefault(m.group(1), []).append(l)
+    blocks = []
+    for name, members in groups.items():
+        dw = [l for l in members if l.kind == "dwconv"]
+        if (len(dw) == 1 and dw[0].stride == 1
+                and members[0].c_in == members[-1].c_out):
+            blocks.append(ResBlockSpec(
+                name=name, convs=tuple(members), ds=None,
+                acts=tuple(_activation(cfg, l) for l in members),
+                join=LINEAR))
+    return tuple(blocks)
+
 
 def residual_blocks(cfg: "CNNConfig") -> Tuple[ResBlockSpec, ...]:
-    """Group a ResNet-family config's layers into residual blocks, by the
-    same ``s{i}b{j}c{k}`` / ``...ds`` naming walk ``cnn_forward`` wires
-    the adds with — the single source of truth for block membership that
-    both the model topology and the compiler's block binding share.
-    Non-ResNet configs (no block structure) return ()."""
+    """The config's blocks with a skip add — the single source of truth
+    for block membership, member activations and joins that the model
+    topology (``cnn_forward``) and the compiler's block binding share.
+
+    ResNet configs: the ``s{i}b{j}c{k}`` / ``...ds`` naming walk; the
+    members take ReLU but the last conv and the downsample, which are
+    linear, and the join is ReLU.  MobileNetV2 configs: the inverted
+    residuals with a skip (:func:`_inverted_residuals`), members ReLU6,
+    ReLU6 and linear, and a linear join.  Other configs have no residual
+    blocks: ()."""
+    if _is_mobilenetv2(cfg):
+        return _inverted_residuals(cfg)
     if not cfg.name.startswith("resnet"):
         return ()
     blocks: List[ResBlockSpec] = []
@@ -196,24 +275,27 @@ def residual_blocks(cfg: "CNNConfig") -> Tuple[ResBlockSpec, ...]:
             j += 1
         ds = [m for m in members if m.name.endswith("ds")]
         convs = tuple(m for m in members if not m.name.endswith("ds"))
+        acts = (RELU,) * (len(convs) - 1) + (LINEAR,) * (1 + len(ds))
         blocks.append(ResBlockSpec(name=prefix, convs=convs,
-                                   ds=ds[0] if ds else None))
+                                   ds=ds[0] if ds else None, acts=acts,
+                                   join=RELU))
         i = j
     return tuple(blocks)
 
 
 def block_shape_signature(block: ResBlockSpec) -> Tuple:
     """Name-independent shape signature of a residual block: member
-    kinds, kernel/channel/stride/input geometry, conv count and
-    downsample presence.  Two blocks with equal signatures run the SAME
+    kinds, kernel/channel/stride/input geometry, conv count, downsample
+    presence, member activations and join.  Two blocks with equal
+    signatures run the SAME
     computation on same-shaped tensors — the compile-time condition for
     folding them into one scanned body (their weights stack along a
     leading axis; only the values differ)."""
     def sig(m: ConvLayerSpec) -> Tuple:
         return (m.kind, m.k_h, m.k_w, m.c_in, m.c_out, m.stride,
                 m.in_h, m.in_w)
-    return ((len(block.convs), block.ds is not None)
-            + tuple(sig(m) for m in block.members))
+    return ((len(block.convs), block.ds is not None, block.acts,
+             block.join) + tuple(sig(m) for m in block.members))
 
 
 def homogeneous_block_runs(cfg: "CNNConfig", min_run: int = 2
@@ -221,10 +303,11 @@ def homogeneous_block_runs(cfg: "CNNConfig", min_run: int = 2
     """Maximal runs of >= ``min_run`` CONSECUTIVE residual blocks (adjacent
     in ``cfg.layers``, no interleaving nodes) with identical
     :func:`block_shape_signature` — e.g. each ResNet-50 stage minus its
-    stride-2 / expanding lead block.  These are the scan candidates the
-    compiler turns into :class:`~repro.core.schedule.ScanGroup`\\ s; the
-    dw/pw alternation of the MobileNets has no residual blocks at all, so
-    they (correctly) yield zero runs."""
+    stride-2 / expanding lead block, or MobileNetV2's same-width inverted
+    residuals after a stage's first.  These are the scan candidates the
+    compiler turns into :class:`~repro.core.schedule.ScanGroup`\\ s.
+    The dw/pw alternation of MobileNetV1 has no residual blocks at all,
+    so it (correctly) yields zero runs."""
     blocks = residual_blocks(cfg)
     if not blocks:
         return ()
@@ -257,6 +340,7 @@ class StemUnitSpec:
     name: str
     conv: ConvLayerSpec
     pool: ConvLayerSpec
+    act: str = RELU                     # the conv's activation
 
     @property
     def members(self) -> Tuple[ConvLayerSpec, ...]:
@@ -271,7 +355,8 @@ def stem_unit(cfg: "CNNConfig") -> Optional[StemUnitSpec]:
     if (len(cfg.layers) >= 2 and cfg.layers[0].kind == "conv"
             and cfg.layers[1].kind == "maxpool"):
         return StemUnitSpec(name=cfg.layers[0].name,
-                            conv=cfg.layers[0], pool=cfg.layers[1])
+                            conv=cfg.layers[0], pool=cfg.layers[1],
+                            act=_activation(cfg, cfg.layers[0]))
     return None
 
 
@@ -611,6 +696,48 @@ def mini_mobilenet(hw: int = 8, width: int = 16,
         layers.append(_gap(c_in, h, w))
     layers.append(ConvLayerSpec("fc", "fc", 1, 1, c_in, 10, 1, 1, 1))
     return CNNConfig("mobilenet-mini", tuple(layers), num_classes=10)
+
+
+def mini_mobilenetv2(hw: int = 16, width: int = 8,
+                     t: int = 6) -> CNNConfig:
+    """MobileNetV2-topology network at executable scale: the rows and
+    names of ``_mobilenet_v2()`` (so ``residual_blocks`` finds its
+    inverted residuals by the same rule), with every kind of block the
+    full network has.  After a 3x3 stem (stride 1 at mini scale):
+
+    * ``ir0``: t = 1 (no expansion), same width, stride 1: a two-member
+      block with a skip;
+    * ``ir1``: stride 2, widening: no skip;
+    * ``ir2``, ``ir3``: same width, stride 1: a run of two skip blocks,
+      one scan group;
+    * ``ir4``: stride 1 but widening: no skip;
+
+    then a 1x1 ``head``, GAP and an fc head."""
+    if hw % 2:
+        raise ValueError("mini_mobilenetv2: hw must be even (ir1 halves "
+                         "the map)")
+    layers: List[ConvLayerSpec] = [
+        ConvLayerSpec("stem", "conv", 3, 3, 3, width, 1, hw, hw)]
+    h = w = hw
+    c_in = width
+    plan = [(1, width, 1), (t, 2 * width, 2), (t, 2 * width, 1),
+            (t, 2 * width, 1), (t, 3 * width, 1)]
+    for i, (ti, c, stride) in enumerate(plan):
+        mid = c_in * ti
+        if ti != 1:
+            layers.append(ConvLayerSpec(
+                f"ir{i}ex", "pwconv", 1, 1, c_in, mid, 1, h, w))
+        layers.append(ConvLayerSpec(
+            f"ir{i}dw", "dwconv", 3, 3, mid, mid, stride, h, w))
+        h, w = h // stride, w // stride
+        layers.append(ConvLayerSpec(
+            f"ir{i}pj", "pwconv", 1, 1, mid, c, 1, h, w))
+        c_in = c
+    layers.append(ConvLayerSpec("head", "pwconv", 1, 1, c_in, 4 * width,
+                                1, h, w))
+    layers.append(_gap(4 * width, h, w))
+    layers.append(ConvLayerSpec("fc", "fc", 1, 1, 4 * width, 10, 1, 1, 1))
+    return CNNConfig("mobilenetv2-mini", tuple(layers), num_classes=10)
 
 
 CNN_CONFIGS = {

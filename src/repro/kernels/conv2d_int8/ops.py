@@ -87,48 +87,58 @@ def conv_tile_for(x_shape, w_shape, *, stride: int, stream: bool,
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "stream", "n_buffers",
-                                             "depthwise", "interpret"))
+                                             "interpret"))
 def conv2d_int8(x, w, *, stride: int = 1, stream: bool = False,
-                n_buffers: int = 2, depthwise: bool = False,
-                interpret: bool = False):
+                n_buffers: int = 2, interpret: bool = False):
     """SAME conv, int8 in / int32 out, via the line-buffer Pallas kernel.
 
-    ``depthwise=True`` selects the grouped (groups == C) engine for
-    HWIO-depthwise weights ``[k_h, k_w, 1, C]`` — the MobileNet path,
-    with the same pinned/streamed weight tiers as the dense conv.
     Input channels are zero-padded to the lane tiling (with matching zero
     weight rows), so the padding contributes nothing to the sums.
     """
     k_h, k_w = w.shape[:2]
-    C = x.shape[-1]
     xl = to_line_layout(x, k_h, k_w, stride)
-    c_pad = xl.shape[-1] - C
-    w_out = -(-x.shape[2] // stride)
-    if depthwise:
-        w = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, c_pad)))
-        y = dwconv_int8_kernel(xl, w, w_out=w_out, stride=stride,
-                               stream=stream, n_buffers=n_buffers,
-                               interpret=interpret)
-        return y[..., :C]
+    c_pad = xl.shape[-1] - x.shape[-1]
     tile = conv_tile_for(x.shape, w.shape, stride=stride, stream=stream,
                          n_buffers=n_buffers)
     w = jnp.pad(w, ((0, 0), (0, 0), (0, c_pad), (0, 0)))
-    return conv2d_int8_kernel(xl, w, tile=tile, w_out=w_out, stride=stride,
+    return conv2d_int8_kernel(xl, w, tile=tile,
+                              w_out=-(-x.shape[2] // stride), stride=stride,
                               stream=stream, n_buffers=n_buffers,
                               interpret=interpret)
 
 
-@functools.partial(jax.jit, static_argnames=("act_scale", "stride", "relu",
+@functools.partial(jax.jit, static_argnames=("stride", "stream", "n_buffers",
+                                             "interpret"))
+def dwconv_int8(x, w, *, stride: int = 1, stream: bool = False,
+                n_buffers: int = 2, interpret: bool = False):
+    """SAME depthwise (groups == C) conv, int8 in / int32 out, for
+    HWIO-depthwise weights ``[k_h, k_w, 1, C]`` — the MobileNet path,
+    with the same pinned/streamed weight tiers as the dense conv.  An
+    entry point of its own, so that its kernel's device events carry
+    this name and not ``conv2d_int8``'s.  Channels are zero-padded to
+    the lane tiling and the padding is sliced off the result."""
+    k_h, k_w = w.shape[:2]
+    C = x.shape[-1]
+    xl = to_line_layout(x, k_h, k_w, stride)
+    w = jnp.pad(w, ((0, 0), (0, 0), (0, 0), (0, xl.shape[-1] - C)))
+    y = dwconv_int8_kernel(xl, w, w_out=-(-x.shape[2] // stride),
+                           stride=stride, stream=stream,
+                           n_buffers=n_buffers, interpret=interpret)
+    return y[..., :C]
+
+
+@functools.partial(jax.jit, static_argnames=("act_scale", "stride", "act",
                                              "stream", "n_buffers",
                                              "interpret"))
 def conv2d_int8_requant(x, w, w_scale, bias, act_scale: float = 0.05, *,
-                        stride: int = 1, relu: bool = True,
+                        stride: int = 1, act: str = "relu",
                         stream: bool = False, n_buffers: int = 2,
                         interpret: bool = False):
-    """Full HPIPE layer engine: conv + per-channel dequant + bias + relu +
-    requantize to int8 for the next engine (models/cnn.py contract)."""
+    """Full HPIPE layer engine: conv + per-channel dequant + bias +
+    activation + requantize to int8 for the next engine (models/cnn.py
+    contract)."""
     y = conv2d_int8(x, w, stride=stride, stream=stream, n_buffers=n_buffers,
                     interpret=interpret)
     y_q, _ = requant_epilogue(y, w_scale, bias, act_scale=act_scale,
-                              relu=relu)
+                              act=act)
     return y_q

@@ -21,11 +21,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.cnn import (POOL_KINDS, CNNConfig, ConvLayerSpec,
-                               ResBlockSpec, residual_blocks, stem_unit)
+from repro.configs.cnn import (POOL_KINDS, RELU, CNNConfig, ConvLayerSpec,
+                               ResBlockSpec, layer_activations,
+                               residual_blocks, stem_unit)
 from repro.kernels.pool_int8.ref import (global_avgpool_int8_ref,
                                          maxpool_int8_ref)
-from repro.kernels.quant import requant_epilogue
+from repro.kernels.quant import requant_epilogue, residual_join
 from repro.models.layers import maybe_axis, MODEL_AXIS
 
 Params = Dict[str, Any]
@@ -59,10 +60,11 @@ def conv_layer_specs(spec: ConvLayerSpec) -> Params:
     return {"w": P(None, None, None, ax), "w_scale": P(ax), "bias": P(ax)}
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "act_scale", "relu"))
+@functools.partial(jax.jit, static_argnames=("spec", "act_scale", "act"))
 def conv_layer_forward(params: Params, spec: ConvLayerSpec, x,
-                       act_scale: float = 0.05, relu: bool = True):
-    """x: [B,H,W,C] int8.  Returns int8 activations (requantized).
+                       act_scale: float = 0.05, act: str = RELU):
+    """x: [B,H,W,C] int8.  Returns int8 activations (requantized) after
+    the activation ``act`` (``configs.cnn.layer_activations``).
 
     Jitted (spec is a hashable frozen dataclass) so the dequant/requant
     epilogue compiles to the same fused float ops as the Pallas engines —
@@ -79,7 +81,7 @@ def conv_layer_forward(params: Params, spec: ConvLayerSpec, x,
         preferred_element_type=jnp.int32)
     # requantize to int8 for the next layer engine (the shared epilogue)
     return requant_epilogue(y, params["w_scale"], params["bias"],
-                            act_scale=act_scale, relu=relu)
+                            act_scale=act_scale, act=act)
 
 
 def init_cnn_params(key, cfg: CNNConfig) -> Params:
@@ -103,18 +105,18 @@ def pool_forward(spec: ConvLayerSpec, x, act_scale: float = 0.05):
     return global_avgpool_int8_ref(x, act_scale=act_scale)
 
 
-# engine(spec, layer_params, x, relu) -> Optional[(y_q, y_float)].  The
+# engine(spec, layer_params, x, act) -> Optional[(y_q, y_float)].  The
 # per-layer dispatch hook the pipeline executor plugs in: it routes each
 # layer to its compile-time LayerEngine binding (repro.compiler.engines);
 # returning None falls back to the jnp reference path here.
-EngineHook = Callable[[ConvLayerSpec, Params, jnp.ndarray, bool],
+EngineHook = Callable[[ConvLayerSpec, Params, jnp.ndarray, str],
                       Optional[Tuple[jnp.ndarray, Optional[jnp.ndarray]]]]
 
 # block_engine(block, params, x) -> Optional[y_q].  The block-granular
 # dispatch hook: a whole residual block (conv chain + downsample + add +
-# relu) offered as ONE unit, for pipelines that bound it to a fused block
-# engine (res_block_int8).  Returning None falls back to per-layer
-# execution below.
+# join activation) offered as ONE unit, for pipelines that bound it to a
+# fused block engine (res_block_int8).  Returning None falls back to
+# per-layer execution below.
 BlockEngineHook = Callable[[ResBlockSpec, Params, jnp.ndarray],
                            Optional[jnp.ndarray]]
 
@@ -141,9 +143,11 @@ def cnn_forward(params: Params, cfg: CNNConfig, images,
     engines by passing ``engine``/``block_engine``).
 
     images: [B,224,224,3] (or reduced) int8.  Returns logits [B,classes].
-    Residual/downsample wiring for ResNets comes from
-    ``configs.cnn.residual_blocks`` — the same grouping the compiler's
-    block binding uses, so the topology and the bindings cannot drift.
+    Residual/downsample wiring (ResNet blocks, MobileNetV2's inverted
+    residuals) and every layer's activation come from
+    ``configs.cnn.residual_blocks`` / ``layer_activations`` — the same
+    grouping the compiler's block binding uses, so the topology and the
+    bindings cannot drift.
     Pooling is NOT wired here: maxpool and global-average-pool are
     first-class graph nodes in ``cfg.layers``, offered to the engine hook
     like any conv (the compiler binds them to the pool engines) — nothing
@@ -179,14 +183,17 @@ def cnn_forward(params: Params, cfg: CNNConfig, images,
     there would silently drop the skip connection.
     """
 
-    def apply_layer(spec: ConvLayerSpec, x, relu: bool = True):
+    acts = layer_activations(cfg)
+
+    def apply_layer(spec: ConvLayerSpec, x):
+        act = acts[spec.name]
         if engine is not None:
-            out = engine(spec, params.get(spec.name, {}), x, relu)
+            out = engine(spec, params.get(spec.name, {}), x, act)
             if out is not None:
                 return out
         if spec.is_pool:
             return pool_forward(spec, x), None
-        return conv_layer_forward(params[spec.name], spec, x, relu=relu)
+        return conv_layer_forward(params[spec.name], spec, x, act=act)
 
     x = images
     layers = list(cfg.layers)
@@ -221,7 +228,7 @@ def cnn_forward(params: Params, cfg: CNNConfig, images,
                 i += 2
                 continue
         if spec.is_pool:
-            x, _ = apply_layer(spec, x, relu=False)
+            x, _ = apply_layer(spec, x)
             i += 1
             continue
         if name in blocks:
@@ -240,26 +247,17 @@ def cnn_forward(params: Params, cfg: CNNConfig, images,
                     continue
             identity = x
             h = x
-            for ci, cspec in enumerate(blk.convs):
-                last = ci == len(blk.convs) - 1
-                h, _ = apply_layer(cspec, h, relu=not last)
+            for cspec in blk.convs:
+                h, _ = apply_layer(cspec, h)
             if blk.ds is not None:
-                identity, _ = apply_layer(blk.ds, identity, relu=False)
-            y = h.astype(jnp.int32) + identity.astype(jnp.int32)
-            x = jnp.clip(y, -127, 127).astype(jnp.int8)
-            x = jnp.where(x > 0, x, 0)                      # relu on int8
+                identity, _ = apply_layer(blk.ds, identity)
+            x = residual_join(h, identity, blk.join)
             i += len(blk.members)
             continue
-        if name.startswith("fc") or name in ("head0", "head1", "head"):
-            # the map reaching an fc head is whatever the graph's explicit
-            # pool nodes produced — no implicit GAP here anymore
-            last = i == len(layers) - 1
-            x, y_f = apply_layer(spec, x, relu=not last)
-            if last:
-                return y_f.reshape(y_f.shape[0], -1)
-            i += 1
-            continue
-        x, _ = apply_layer(spec, x)
+        x, y_f = apply_layer(spec, x)
+        if i == len(layers) - 1:
+            # the last layer's float pre-quantization output: the logits
+            return y_f.reshape(y_f.shape[0], -1)
         i += 1
     if stop < len(layers):
         return x                  # int8 stage-boundary activation

@@ -98,8 +98,8 @@ def test_first_differing_layer_finds_a_wrong_engine():
         def stats(self, sched, batch):
             return pool.stats(sched, batch)
 
-        def run(self, ctx, sched, p, x, relu):
-            y, y_f, st = pool.run(ctx, sched, p, x, relu)
+        def run(self, ctx, sched, p, x, act):
+            y, y_f, st = pool.run(ctx, sched, p, x, act)
             y = jnp.minimum(y.astype(jnp.int32) + 1, 127)
             return y.astype(jnp.int8), y_f, st
 

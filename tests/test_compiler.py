@@ -259,9 +259,9 @@ def test_engine_registry_override_round_trips():
         def vmem_bytes(self, spec, sched):
             return builtin.vmem_bytes(spec, sched)
 
-        def run(self, ctx, sched, params, x, relu):
+        def run(self, ctx, sched, params, x, act):
             calls.append(sched.spec.name)
-            return builtin.run(ctx, sched, params, x, relu)
+            return builtin.run(ctx, sched, params, x, act)
 
     try:
         cp = compiler.compile(MINI, TPU_INTERPRET)
@@ -293,8 +293,8 @@ def test_same_name_override_restores_builtin_on_unregister():
         def vmem_bytes(self, spec, sched):
             return builtin.vmem_bytes(spec, sched)
 
-        def run(self, ctx, sched, params, x, relu):
-            return builtin.run(ctx, sched, params, x, relu)
+        def run(self, ctx, sched, params, x, act):
+            return builtin.run(ctx, sched, params, x, act)
 
     try:
         assert compiler.get_engine("conv2d_int8") is not builtin

@@ -172,9 +172,9 @@ def test_fused_engine_override_traces_once(setup):
         def vmem_bytes(self, spec, sched):
             return builtin.vmem_bytes(spec, sched)
 
-        def run(self, ctx, sched, p, xx, relu):
+        def run(self, ctx, sched, p, xx, act):
             calls.append(sched.spec.name)
-            return builtin.run(ctx, sched, p, xx, relu)
+            return builtin.run(ctx, sched, p, xx, act)
 
     try:
         probed = compiler.compile(MINI, TPU_INTERPRET)

@@ -222,8 +222,8 @@ def test_depthwise_kernel_matches_reference(rng_key):
             x, w, window_strides=(stride, stride), padding="SAME",
             dimension_numbers=("NHWC", "HWIO", "NHWC"),
             feature_group_count=8, preferred_element_type=jnp.int32)
-        from repro.kernels.conv2d_int8.ops import conv2d_int8
+        from repro.kernels.conv2d_int8.ops import dwconv_int8
         for stream in (False, True):
-            out = conv2d_int8(x, w, stride=stride, stream=stream,
-                              depthwise=True, interpret=True)
+            out = dwconv_int8(x, w, stride=stride, stream=stream,
+                              interpret=True)
             assert bool(jnp.all(out == ref)), (stride, stream)

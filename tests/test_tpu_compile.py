@@ -15,6 +15,8 @@ that runs this file loads the TPU compiler library.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -22,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.compiler.engines import StreamMatmulFCEngine
 from repro.configs.cnn import ConvLayerSpec
-from repro.kernels.conv2d_int8.ops import conv2d_int8
+from repro.kernels.conv2d_int8.ops import conv2d_int8, dwconv_int8
 from repro.kernels.pool_int8.ops import global_avgpool_int8, maxpool_int8
 from repro.kernels.stream_matmul.ops import stream_matmul
 
@@ -93,14 +95,24 @@ def test_conv2d_int8_compiles_at_batch(one_chip, case, batch):
     _compile_conv(one_chip, case, batch)
 
 
-@pytest.mark.parametrize("stride,hw,c", [(1, 112, 32), (2, 112, 96)])
+#: every depthwise shape of MobileNetV2 (ir0 ... ir16) as (stride, H=W, C)
+DW_CASES = [(1, 112, 32), (2, 112, 96), (1, 56, 144), (2, 56, 144),
+            (1, 28, 192), (2, 28, 192), (1, 14, 384), (1, 14, 576),
+            (2, 14, 576), (1, 7, 960)]
+
+
+@pytest.mark.parametrize("stride,hw,c", DW_CASES)
 @pytest.mark.parametrize("stream", [False, True])
 def test_dwconv_int8_compiles(one_chip, stride, hw, c, stream):
-    """MobileNetV2's depthwise 3x3 at stride 1 (ir0) and 2 (ir1)."""
-    _compile_for_chip(
-        lambda x, w: conv2d_int8(x, w, stride=stride, stream=stream,
-                                 depthwise=True, interpret=False),
+    """MobileNetV2's depthwise 3x3 at each of its shapes, from 112x112x32
+    (ir0) and 112x112x96 at stride 2 (ir1) to 7x7x960 (ir16), under its
+    own entry point's name."""
+    text = _compile_for_chip(
+        lambda x, w: dwconv_int8(x, w, stride=stride, stream=stream,
+                                 interpret=False),
         one_chip, (B, hw, hw, c), (3, 3, 1, c))
+    calls = re.findall(r"%([\w.-]+) = \S+ custom-call\(", text)
+    assert calls and all(c.startswith("dwconv_int8") for c in calls), calls
 
 
 def test_maxpool_int8_compiles(one_chip):
